@@ -25,6 +25,7 @@ import numpy as np
 
 from repro import (Problem, SolverSpec, Weights, make_fleet, make_system,
                    obs, solve)
+from repro.compile_cache import use_compile_cache
 from repro.core import total_energy, total_time
 from repro.core.baselines import comm_only, comp_only, min_pixel, rand_pixel, scheme1
 from repro.core.types import dbm_to_watt
@@ -1055,6 +1056,7 @@ BENCHES = {
 
 
 def main() -> None:
+    use_compile_cache()
     args = sys.argv[1:]
     json_path = None
     if "--json" in args:

@@ -42,6 +42,7 @@ from repro.core.bcd import (_FIXED_COLS, _LEDGER_COLS, _allocate_fixed_impl,
                             _init_carry_state, _materialize_history, BCDResult,
                             SolveCounters, initial_allocation)
 from repro.core.types import Allocation, SystemParams
+from repro.kernels.ops import kernel_mode
 
 from .problem import Problem, weights_leaf
 from .spec import SolverSpec, warn_tol_floor
@@ -234,7 +235,7 @@ def _solve_single(p: Problem, spec: SolverSpec, sysp, init) -> BCDResult:
     warr = weights_leaf(p.weights, state0[0].dtype)
     out = _allocate_impl(
         sysp, warr, acc, state0, spec.max_iters, spec.tol,
-        spec.sp1_method, spec.sp2_method, spec.sp2_iters)
+        spec.sp1_method, spec.sp2_method, spec.sp2_iters, kernel_mode())
     return _bcd_result(out, alloc0, spec, _LEDGER_COLS, "objective",
                        with_s_relaxed=True)
 
@@ -336,7 +337,7 @@ def _solve_fleet(p: Problem, spec: SolverSpec, sysp, init):
     C = int(jnp.asarray(sysp.gain).shape[0])
     warr = weights_leaf(p.weights, dtype, cells=C)
     fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
-                        spec.sp2_method, spec.sp2_iters,
+                        spec.sp2_method, spec.sp2_iters, kernel_mode(),
                         with_init=init is not None)
     out = jax.vmap(fn)(sysp, warr) if init is None \
         else jax.vmap(fn)(sysp, warr, init)
@@ -360,8 +361,8 @@ def _solve_region(p: Problem, spec: SolverSpec, sysp, init):
                                  Cp), mesh)
     out = _region_solve_impl(sysb, warr, initb, jnp.asarray(spec.tol, dtype),
                              acc, spec.max_iters, spec.sp1_method,
-                             spec.sp2_method, spec.sp2_iters, mesh,
-                             spec.lockstep, init is not None)
+                             spec.sp2_method, spec.sp2_iters, kernel_mode(),
+                             mesh, spec.lockstep, init is not None)
     fleet = _slice_fleet(_fleet_result(out, spec.max_iters, dtype), C)
     return RegionResult(fleet=fleet,
                         _stats_packed=_pack_stats(fleet, n_shards=D),
@@ -378,7 +379,8 @@ def _solve_rounds(p: Problem, spec: SolverSpec, sysp, init):
     alloc0 = init if init is not None else initial_allocation(sysp)
     state0 = _init_carry_state(sysp, alloc0)
     warr = weights_leaf(p.weights, state0[0].dtype)
-    return _result(_run_rounds_impl(sysp, warr, acc, p.key, state0, cfg))
+    return _result(_run_rounds_impl(sysp, warr, acc, p.key, state0, cfg,
+                                    kernel_mode()))
 
 
 def _solve_rounds_fleet(p: Problem, spec: SolverSpec, sysp, init):
@@ -395,7 +397,7 @@ def _solve_rounds_fleet(p: Problem, spec: SolverSpec, sysp, init):
     init_state = None if init is None else jax.vmap(_init_carry_state)(
         sysp, init)
     return _result(_run_rounds_fleet_impl(sysp, warr, acc, keys, init_state,
-                                          cfg))
+                                          cfg, kernel_mode()))
 
 
 def _solve_rounds_region(p: Problem, spec: SolverSpec, sysp, init):
@@ -421,8 +423,9 @@ def _solve_rounds_region(p: Problem, spec: SolverSpec, sysp, init):
         sysp, init)
     initb = None if init_state is None else place_cells(
         pad_cells(init_state, Cp), mesh)
-    out = _region_rounds_impl(sysb, warr, keysb, initb, acc, cfg, mesh,
-                              spec.lockstep, init_state is not None)
+    out = _region_rounds_impl(sysb, warr, keysb, initb, acc, cfg,
+                              kernel_mode(), mesh, spec.lockstep,
+                              init_state is not None)
     res = _result(out)
     cut = lambda x: x[:C]
     return RoundsResult(

@@ -29,7 +29,7 @@ from jax import lax
 from . import energy as en
 from .accuracy import AccuracyModel
 from .energy import rate as _rate
-from .sp1 import _SP1_IMPLS, _solve_sp1_fixed_impl
+from .sp1 import _solve_sp1_fixed_impl, sp1_engine
 from .sp2 import _golden_argmin, _sp2_direct_impl, _sp2_jong_core, r_min
 from .types import Allocation, SystemParams, Weights
 
@@ -241,18 +241,21 @@ def _pack_counters(iters, ledger, max_iters: int, sp2_col: int,
 
 
 @partial(jax.jit, static_argnames=("acc", "max_iters", "sp1_method",
-                                   "sp2_method", "sp2_iters"))
+                                   "sp2_method", "sp2_iters", "kernel"))
 def _allocate_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
                    state0, max_iters: int, tol,
-                   sp1_method: str, sp2_method: str, sp2_iters: int):
+                   sp1_method: str, sp2_method: str, sp2_iters: int,
+                   kernel: str):
     """Device-resident Algorithm 2. Returns
     (B, p, f, s, s_hat, T, iters, converged, ledger, counters) — the
-    trailing `counters` is the packed `_COUNTER_COLS` effort vector."""
+    trailing `counters` is the packed `_COUNTER_COLS` effort vector.
+    `kernel` is the SP1 sweep kernel's mode, resolved by the caller outside
+    jit (`kernels.ops.kernel_mode`)."""
     from .sp1 import dual_evals_per_iter
 
     dtype = state0[0].dtype
     warr_sp1 = jnp.stack([warr[0], jnp.maximum(warr[1], 1e-9), warr[2]])
-    solve_sp1 = _SP1_IMPLS[sp1_method]
+    solve_sp1 = sp1_engine(sp1_method, kernel)
 
     def step(state):
         B, p, _, _, _, _ = state
@@ -461,7 +464,7 @@ def stack_systems(systems: Sequence[SystemParams], xp=jnp) -> SystemParams:
 
 
 def _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
-                   sp2_iters, with_init: bool):
+                   sp2_iters, kernel, with_init: bool):
     """Per-cell solver closure shared by the fleet vmap and the region
     shard_map (`api.solve._solve_fleet` / `_solve_region`). The weights
     array is a *vmapped operand* — each cell carries its own traced (3,)
@@ -470,7 +473,7 @@ def _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
     def warm(sysc, warr_c, alloc0):
         state0 = _init_carry_state(sysc, alloc0)
         return _allocate_impl(sysc, warr_c, acc, state0, max_iters, tol,
-                              sp1_method, sp2_method, sp2_iters)
+                              sp1_method, sp2_method, sp2_iters, kernel)
 
     if with_init:
         return warm

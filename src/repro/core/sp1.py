@@ -18,15 +18,15 @@ formulation:
 
   * method="sweep" (default): a batched T-grid sweep — every round evaluates
     Sigma_n lambda_n(T) for a whole grid of candidate deadlines in one
-    device pass through `kernels.ops.sp1_lambda_sum` (Pallas on TPU, the
-    pure-jnp ref oracle on CPU) and re-grids geometrically inside the
-    sign-change bracket, finishing with secant interpolation. For the
-    paper's LinearAccuracy the inner inversion lambda_n(T) is CLOSED FORM
-    (the clipping regimes of A.2/A.3 each invert exactly — see
-    `kernels.sp1_sweep.lambda_of_T_linear`), so one sweep costs O(grid) per
-    device instead of O(outer x inner) bisection steps; generic concave
-    accuracy models run the same sweep with a vmapped per-grid-point
-    bisection for lambda_n(T).
+    device pass through `kernels.ops.sp1_lambda_sum` (in the kernel mode the
+    caller resolved outside jit — see `kernels.ops.kernel_mode`) and
+    re-grids geometrically inside the sign-change bracket, finishing with
+    secant interpolation. For the paper's LinearAccuracy the inner
+    inversion lambda_n(T) is CLOSED FORM (the clipping regimes of A.2/A.3
+    each invert exactly — see `kernels.sp1_sweep.lambda_of_T_linear`), so
+    one sweep costs O(grid) per device instead of O(outer x inner)
+    bisection steps; generic concave accuracy models run the same sweep
+    with a vmapped per-grid-point bisection for lambda_n(T).
   * method="bisect": the original nested bisection (inner lambda, outer T),
     kept bit-stable as the parity oracle for the sweep.
 
@@ -282,16 +282,17 @@ def _solve_sp1_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
     return _finish_sp1(sys, w, acc, q, lam, tt, T)
 
 
-@partial(jax.jit, static_argnames=("acc",))
+@partial(jax.jit, static_argnames=("acc", "kernel"))
 def _solve_sp1_sweep_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
-                          tt: Array):
+                          tt: Array, kernel: str):
     """Batched T-grid sweep engine (method="sweep", the default).
 
     Each round evaluates Sigma_n lambda_n(T) for a whole geometric grid of
     candidate deadlines in one pass (`kernels.ops.sp1_lambda_sum` for
     LinearAccuracy, a vmapped lambda-bisection otherwise), narrows to the
     sign-change bracket of Sigma lambda - w2 Rg, and finishes with a secant
-    step — replacing `_OUTER_ITERS` sequential outer bisections."""
+    step — replacing `_OUTER_ITERS` sequential outer bisections. `kernel`
+    is the resolved mode of the sweep kernel (`kernels.ops.KERNEL_MODES`)."""
     from ..kernels import ops as kops
     from ..kernels.sp1_sweep import N_CONSTS, lambda_of_T_linear
 
@@ -310,7 +311,8 @@ def _solve_sp1_sweep_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
              sys.s_lo, sys.s_hi, lam_hi)]))
 
         def lam_sum(grid):
-            return kops.sp1_lambda_sum(grid, q, tt, consts).astype(dtype)
+            return kops.sp1_lambda_sum(grid, q, tt, consts,
+                                       impl=kernel).astype(dtype)
 
         n_grid, rounds = _SWEEP_POINTS, _SWEEP_ROUNDS
     else:
@@ -343,7 +345,16 @@ def _solve_sp1_sweep_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
     return _finish_sp1(sys, w, acc, q, lam, tt, T)
 
 
-_SP1_IMPLS = {"sweep": _solve_sp1_sweep_impl, "bisect": _solve_sp1_impl}
+def sp1_engine(method: str, kernel: str):
+    """The SP1 solve `(sys, warr, acc, tt) -> (f, s, s_hat, T)` for one BCD
+    step: `method` picks the engine, `kernel` is the sweep kernel's mode,
+    resolved by `kernels.ops.kernel_mode` outside any jit (the bisection
+    runs no kernel and ignores it)."""
+    if method == "sweep":
+        return partial(_solve_sp1_sweep_impl, kernel=kernel)
+    if method == "bisect":
+        return _solve_sp1_impl
+    raise ValueError(f"method must be sweep|bisect, got {method!r}")
 
 
 def dual_evals_per_iter(sp1_method: str, acc: AccuracyModel) -> int:
@@ -375,13 +386,13 @@ def solve_sp1(sys: SystemParams, w: Weights, acc: AccuracyModel,
 
     method: "sweep" (batched T-grid dual sweep, the default) or "bisect"
     (the original nested bisection, kept as the parity oracle)."""
+    from ..kernels.ops import kernel_mode
     from .energy import rate
 
-    if method not in _SP1_IMPLS:
-        raise ValueError(f"method must be sweep|bisect, got {method!r}")
+    engine = sp1_engine(method, kernel_mode())
     tt = sys.bits / jnp.maximum(rate(sys, bandwidth, power), 1e-12)
     warr = jnp.asarray([w.w1, max(w.w2, 1e-9), w.rho], tt.dtype)
-    return _SP1_IMPLS[method](sys, warr, acc, tt)
+    return engine(sys, warr, acc, tt)
 
 
 @partial(jax.jit, static_argnames=("acc",))

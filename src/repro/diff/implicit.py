@@ -76,6 +76,7 @@ from ..core.sp2 import (G, _b_min, _clamp_rmin, _denergy2_dB2, _denergy_dB,
                         _p_rate, _sp2_direct_impl, r_min, sp2_stationarity)
 from ..core.types import (_SYS_ARRAYS, _SYS_SCALARS, Allocation, SystemParams,
                           Weights)
+from ..kernels.ops import kernel_mode
 
 Array = jnp.ndarray
 
@@ -300,7 +301,7 @@ def _normalize_weights(wr: Array) -> Array:
 
 
 def _cell_grad(sysc: SystemParams, lv, wr, initc, acc, spec: SolverSpec,
-               wrt, adjoint_iters: int):
+               wrt, adjoint_iters: int, kernel: str):
     """Metrics + per-metric gradients for one cell. `lv` duplicates the
     `wrt` leaves of `sysc` as the differentiated operands."""
     alloc0 = initc if initc is not None else initial_allocation(sysc)
@@ -314,7 +315,7 @@ def _cell_grad(sysc: SystemParams, lv, wr, initc, acc, spec: SolverSpec,
         sys = build(lv_)
         out = _allocate_impl(sys, warr, acc, state0, spec.max_iters,
                              spec.tol, spec.sp1_method, spec.sp2_method,
-                             spec.sp2_iters)
+                             spec.sp2_iters, kernel)
         return out[0], out[1]
 
     def fwd(lv_, warr):
@@ -366,11 +367,13 @@ def _cell_grad(sysc: SystemParams, lv, wr, initc, acc, spec: SolverSpec,
 
 
 @partial(jax.jit,
-         static_argnames=("acc", "spec", "wrt", "adjoint_iters", "fleet"))
+         static_argnames=("acc", "spec", "wrt", "adjoint_iters", "fleet",
+                          "kernel"))
 def _solve_and_grad_impl(sysp, leaf_vals, warr_raw, init, acc, spec, wrt,
-                         adjoint_iters, fleet):
+                         adjoint_iters, fleet, kernel):
     def cell(sysc, lv, wr, initc):
-        return _cell_grad(sysc, lv, wr, initc, acc, spec, wrt, adjoint_iters)
+        return _cell_grad(sysc, lv, wr, initc, acc, spec, wrt, adjoint_iters,
+                          kernel)
 
     if fleet:
         return jax.vmap(cell)(sysp, leaf_vals, warr_raw, init)
@@ -487,7 +490,7 @@ def solve_and_grad(problem: Problem, spec: Optional[SolverSpec] = None, *,
 
     mvec, d_lv, d_wr, alloc = _solve_and_grad_impl(
         sysp, leaf_vals, warr_raw, init, acc, spec, wrt,
-        int(adjoint_iters), cells is not None)
+        int(adjoint_iters), cells is not None, kernel_mode())
 
     fleet = cells is not None
     value = {m: _take_metric(mvec, i, fleet) for i, m in enumerate(METRICS)}

@@ -48,10 +48,11 @@ def _masked_max(x: Array, mask: Array) -> Array:
 
 
 def _cell_engine(sys: SystemParams, warr: Array, acc: AccuracyModel,
-                 key: jax.Array, state0, cfg: RoundsConfig):
+                 key: jax.Array, state0, cfg: RoundsConfig, kernel: str):
     """One cell's R-round scan. Returns (final BCD state, ledger (R, cols),
     staleness codes (R, N) int32, realized gains (R, N), allocated
-    resolutions (R, N))."""
+    resolutions (R, N)). `kernel` is the SP1 sweep kernel's mode, resolved
+    outside jit (`kernels.ops.kernel_mode`)."""
     dtype = state0[0].dtype
     n = sys.gain.shape[0]
     K = cfg.max_staleness
@@ -84,7 +85,7 @@ def _cell_engine(sys: SystemParams, warr: Array, acc: AccuracyModel,
             sys_r, initial_allocation(sys_r))
         B, p, f, s, s_hat, T, iters, conv, _, counters = _allocate_impl(
             sys_r, warr, acc, state_in, cfg.bcd_iters, cfg.bcd_tol,
-            cfg.sp1_method, cfg.sp2_method, cfg.sp2_iters)
+            cfg.sp1_method, cfg.sp2_method, cfg.sp2_iters, kernel)
         state = (B, p, f, s, s_hat, T)
         alloc = Allocation(bandwidth=B, power=p, freq=f, resolution=s,
                            s_relaxed=s_hat, T=T)
@@ -160,23 +161,24 @@ def _cell_engine(sys: SystemParams, warr: Array, acc: AccuracyModel,
     return state, ledger, codes, gains, res
 
 
-@partial(jax.jit, static_argnames=("acc", "cfg"))
-def _run_rounds_impl(sys, warr, acc, key, state0, cfg):
-    return _cell_engine(sys, warr, acc, key, state0, cfg)
+@partial(jax.jit, static_argnames=("acc", "cfg", "kernel"))
+def _run_rounds_impl(sys, warr, acc, key, state0, cfg, kernel):
+    return _cell_engine(sys, warr, acc, key, state0, cfg, kernel)
 
 
-@partial(jax.jit, static_argnames=("acc", "cfg"))
-def _run_rounds_fleet_impl(sys_batch, warr, acc, keys, init_state, cfg):
+@partial(jax.jit, static_argnames=("acc", "cfg", "kernel"))
+def _run_rounds_fleet_impl(sys_batch, warr, acc, keys, init_state, cfg,
+                           kernel):
     """warr is the (C, 3) per-cell weights stack — a traced vmapped operand,
     so mixed per-cell weights share this one jit cache entry."""
     if init_state is None:
         def one(sysc, warr_c, kc):
             st = _init_carry_state(sysc, initial_allocation(sysc))
-            return _cell_engine(sysc, warr_c, acc, kc, st, cfg)
+            return _cell_engine(sysc, warr_c, acc, kc, st, cfg, kernel)
         return jax.vmap(one)(sys_batch, warr, keys)
 
     def one(sysc, warr_c, kc, st):
-        return _cell_engine(sysc, warr_c, acc, kc, st, cfg)
+        return _cell_engine(sysc, warr_c, acc, kc, st, cfg, kernel)
     return jax.vmap(one)(sys_batch, warr, keys, init_state)
 
 

@@ -1,9 +1,17 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in interpret mode — the kernel
-body runs as traced JAX ops, validating the exact code that compiles for TPU.
-On TPU backends they compile natively. `REPRO_FORCE_INTERPRET=1` forces
-interpret mode everywhere.
+How a kernel runs is one static *mode*, resolved by `kernel_mode()` outside
+any jit and handed down as a static argument, so it is part of the jit key
+of every program that reaches the kernel:
+
+  "mosaic"     compiled Pallas — the default on a TPU backend;
+  "interpret"  the kernel body run as traced JAX ops — only when asked for,
+               by REPRO_FORCE_INTERPRET=1 or impl="interpret";
+  "ref"        the pure-jnp oracle — the default on any other backend (the
+               CPU test path), or when asked for by impl="ref".
+
+The LLM-stack kernels (flash attention, Mamba and RWKV-6 scans) have no ref
+form: under "ref" they run their kernel body in interpret mode.
 """
 from __future__ import annotations
 
@@ -22,31 +30,64 @@ from repro.kernels.rwkv6_scan import rwkv6_scan as _rwkv
 from repro.kernels.sp1_sweep import sp1_lambda_sum as _sp1_sweep
 from repro.kernels.waterfill import waterfill_gprime as _waterfill
 
+KERNEL_MODES = ("mosaic", "interpret", "ref")
 
-def _interpret() -> bool:
+
+def kernel_mode(impl: str = "auto") -> str:
+    """Resolve `impl` to one of `KERNEL_MODES`. "auto" is compiled Pallas
+    on a TPU and the ref oracle elsewhere; REPRO_FORCE_INTERPRET=1 turns
+    "auto" into interpret mode on any backend. An explicit mode is returned
+    as is: "mosaic" off a TPU fails when the kernel lowers, it is never
+    swapped for another mode. Call it outside jit and pass the result down
+    as a static argument."""
+    if impl in KERNEL_MODES:
+        return impl
+    if impl != "auto":
+        raise ValueError(
+            f"impl must be auto or one of {KERNEL_MODES}, got {impl!r}")
     if os.environ.get("REPRO_FORCE_INTERPRET"):
-        return True
-    return jax.default_backend() != "tpu"
+        return "interpret"
+    return "mosaic" if jax.default_backend() == "tpu" else "ref"
+
+
+def _interpret(mode: str) -> bool:
+    return mode != "mosaic"
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "block_q",
-                                             "block_k"))
+                                             "block_k", "interpret"))
+def _flash_dispatch(q, k, v, *, causal, window, block_q, block_k, interpret):
+    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
+                  block_k=block_k, interpret=interpret)
+
+
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     block_q: int = 128, block_k: int = 128):
-    return _flash(q, k, v, causal=causal, window=window, block_q=block_q,
-                  block_k=block_k, interpret=_interpret())
+    return _flash_dispatch(q, k, v, causal=causal, window=window,
+                           block_q=block_q, block_k=block_k,
+                           interpret=_interpret(kernel_mode()))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _rwkv_dispatch(r, k, v, logw, u, *, chunk, interpret):
+    return _rwkv(r, k, v, logw, u, chunk=chunk, interpret=interpret)
+
+
 def rwkv6_scan(r, k, v, logw, u, *, chunk: int = 64):
-    return _rwkv(r, k, v, logw, u, chunk=chunk, interpret=_interpret())
+    return _rwkv_dispatch(r, k, v, logw, u, chunk=chunk,
+                          interpret=_interpret(kernel_mode()))
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "block_d"))
-def mamba_scan(dt, A, Bt, Ct, x, *, chunk: int = 64, block_d: int = 256):
+@functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
+def _mamba_dispatch(dt, A, Bt, Ct, x, *, chunk, block_d, interpret):
     return _mamba(dt, A, Bt, Ct, x, chunk=chunk, block_d=block_d,
-                  interpret=_interpret())
+                  interpret=interpret)
+
+
+def mamba_scan(dt, A, Bt, Ct, x, *, chunk: int = 64, block_d: int = 256):
+    return _mamba_dispatch(dt, A, Bt, Ct, x, chunk=chunk, block_d=block_d,
+                           interpret=_interpret(kernel_mode()))
 
 
 def waterfill_compute_dtype(input_dtype):
@@ -60,53 +101,38 @@ def waterfill_compute_dtype(input_dtype):
     return jnp.dtype(input_dtype)
 
 
-def _resolve_impl(impl: str) -> str:
-    """Shared "auto" resolution for the dual-sweep ops: native Pallas on TPU,
-    the pure-jnp ref oracle on CPU, interpret-mode kernel bodies under
-    REPRO_FORCE_INTERPRET=1. Resolved OUTSIDE the jit cache so flipping the
-    env var between calls takes effect (impl is the static cache key)."""
-    if impl not in ("auto", "pallas", "ref"):
-        raise ValueError(f"impl must be auto|pallas|ref, got {impl!r}")
-    if impl == "auto":
-        return "pallas" if (jax.default_backend() == "tpu"
-                            or os.environ.get("REPRO_FORCE_INTERPRET")) else "ref"
-    return impl
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "impl", "dtype"))
+@functools.partial(jax.jit, static_argnames=("block_n", "mode", "dtype"))
 def _waterfill_dispatch(mu, j, rmin, B_total, *, block_n: int,
-                        impl: str, dtype):
-    if impl == "ref":
+                        mode: str, dtype):
+    if mode == "ref":
         return _waterfill_ref(mu.astype(dtype), j.astype(dtype),
                               rmin.astype(dtype), jnp.asarray(B_total, dtype))
     return _waterfill(mu, j, rmin, jnp.asarray(B_total, dtype),
-                      block_n=block_n, interpret=_interpret(), dtype=dtype)
+                      block_n=block_n, interpret=_interpret(mode),
+                      dtype=dtype)
 
 
 def waterfill_gprime(mu, j, rmin, B_total, *, block_n: int = 1024,
                      impl: str = "auto"):
-    """Production entry for the SP2 dual sweep (used by `core.sp2`).
+    """Entry for the SP2 dual sweep (used by `core.sp2`'s thm2 reference).
 
-    impl: "auto" — native Pallas on TPU, the pure-jnp ref oracle on CPU
-          (full input precision, no interpret-mode overhead); setting
-          REPRO_FORCE_INTERPRET=1 routes "auto" through the interpret-mode
-          kernel body instead.  "pallas" / "ref" force a path explicitly.
+    impl: "auto" or a mode of `KERNEL_MODES` (see `kernel_mode`).
     B_total may be a traced scalar (a per-cell leaf in heterogeneous fleets).
     Computes in `waterfill_compute_dtype(mu.dtype)`.
     """
     return _waterfill_dispatch(mu, j, rmin, B_total, block_n=block_n,
-                               impl=_resolve_impl(impl),
+                               mode=kernel_mode(impl),
                                dtype=waterfill_compute_dtype(mu.dtype))
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "impl", "dtype"))
+@functools.partial(jax.jit, static_argnames=("block_n", "mode", "dtype"))
 def _sp1_sweep_dispatch(T_grid, q, tt, consts, *, block_n: int,
-                        impl: str, dtype):
-    if impl == "ref":
+                        mode: str, dtype):
+    if mode == "ref":
         return _sp1_sweep_ref(T_grid.astype(dtype), q.astype(dtype),
                               tt.astype(dtype), consts.astype(dtype))
     return _sp1_sweep(T_grid, q, tt, consts, block_n=block_n,
-                      interpret=_interpret(), dtype=dtype)
+                      interpret=_interpret(mode), dtype=dtype)
 
 
 def sp1_lambda_sum(T_grid, q, tt, consts, *, block_n: int = 1024,
@@ -117,9 +143,10 @@ def sp1_lambda_sum(T_grid, q, tt, consts, *, block_n: int = 1024,
     T_grid: (M,) candidate round deadlines; q/tt: (N,) per-device cycle and
     transmission-time coefficients; consts: (sp1_sweep.N_CONSTS,) scalar
     coefficient vector (may be traced — per-cell leaves vary across a
-    heterogeneous fleet). impl semantics match `waterfill_gprime`; computes
-    in `waterfill_compute_dtype(T_grid.dtype)`.
+    heterogeneous fleet). impl: "auto" or a mode of `KERNEL_MODES`; the
+    solver passes the mode it resolved outside its own jit. Computes in
+    `waterfill_compute_dtype(T_grid.dtype)`.
     """
     return _sp1_sweep_dispatch(T_grid, q, tt, consts, block_n=block_n,
-                               impl=_resolve_impl(impl),
+                               mode=kernel_mode(impl),
                                dtype=waterfill_compute_dtype(T_grid.dtype))

@@ -17,9 +17,15 @@ through the exact forward map, and keep the smallest lambda among the
 candidates with minimal makespan error (the bisection's left-edge convention
 on flat segments, and exactly 0 for devices already meeting the deadline).
 
-Grid (N/bn,), VMEM blocks of (q, tt) device parameters, the (M,) T-grid
-replicated per step, scalar coefficients in SMEM, partial sums accumulated
-into the (M,) output across sequential grid steps.
+Layout: grid (N/bn,) over device blocks; every operand is 2-D so that each
+block's last two dims are either full or (8, 128)-tiled — the T-grid (M, 1)
+whole, the scalar coefficients (1, N_CONSTS) whole in SMEM, q/tt (1, bn)
+lane blocks, and the (M, 1) partial sums accumulated across the sequential
+grid steps. `vmap`
+(the fleet, region and rounds solves) prepends a squeezed cell dimension to
+every block and a cell axis to the grid, which keeps that rule intact at any
+number of cells. The math uses only ops Mosaic lowers: the cube root and
+the fractional powers go through exp/log (see `_cbrt_nonneg`).
 
 Oracle: kernels.ref.sp1_lambda_sum_ref (same closed form at full input
 precision); parity vs the nested bisection is tested in tests/test_sp1_kkt.py.
@@ -33,8 +39,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# consts vector layout fed to the kernel (SMEM): index -> meaning
+# consts vector layout fed to the kernel: index -> meaning
 N_CONSTS = 8   # [k3, rho_slope, f_min, f_max, s_lo, s_hi, lam_hi, unused]
+
+_LANES = 128
+
+
+def _cbrt_nonneg(x):
+    """cbrt for x >= 0 from exp/log (Mosaic has no cbrt lowering), with one
+    Newton step restoring ~1 ulp. x = 0 gives exactly 0 (log 0 = -inf) and
+    x = inf gives inf; both skip the Newton step, which would be 0/0 there."""
+    y = jnp.exp(jnp.log(x) * (1.0 / 3.0))
+    ok = (y > 0.0) & (y < jnp.inf)
+    ys = jnp.where(ok, y, 1.0)
+    return jnp.where(ok, ys - (ys - x / (ys * ys)) * (1.0 / 3.0), y)
 
 
 def lambda_of_T_linear(T, q, tt, k3, rhok, f_min, f_max, s_lo, s_hi, lam_hi):
@@ -61,7 +79,7 @@ def lambda_of_T_linear(T, q, tt, k3, rhok, f_min, f_max, s_lo, s_hi, lam_hi):
     alpha = 0.5 * k3 * q
 
     def makespan_err(lam):                    # exact forward map, vs target
-        f = jnp.clip(jnp.cbrt(lam / jnp.maximum(k3, tiny)), f_min, f_max)
+        f = jnp.clip(_cbrt_nonneg(lam / jnp.maximum(k3, tiny)), f_min, f_max)
         psi = 2.0 * alpha * f ** 2 + 2.0 * lam * q / jnp.maximum(f, 1e-9)
         s = jnp.clip(rhok / jnp.maximum(psi, tiny), s_lo, s_hi)
         return jnp.abs(q * s ** 2 / jnp.maximum(f, 1e-9) - t_c)
@@ -76,21 +94,24 @@ def lambda_of_T_linear(T, q, tt, k3, rhok, f_min, f_max, s_lo, s_hi, lam_hi):
         return k3 * f ** 3
 
     # both interior: f^5 = q rhok^2 / (36 alpha^2 t_c) with alpha = k3 q / 2,
-    # i.e. f = (rhok / (3 k3))^(2/5) * (q t_c)^(-1/5). Factored this way so
-    # kappa-scale coefficients never square: alpha^2 ~ 1e-45 underflows f32
-    # (the fleet bench dtype) even though f itself is representable.
-    f6 = (rhok / jnp.maximum(3.0 * k3, tiny)) ** 0.4 \
-        * jnp.maximum(q * t_c, tiny) ** -0.2
-    cands = jnp.stack(jnp.broadcast_arrays(
-        jnp.zeros_like(t_c),
-        cand_f_clipped(f_min), cand_f_clipped(f_max),
-        cand_s_clipped(s_lo), cand_s_clipped(s_hi),
-        k3 * f6 ** 3))
-    cands = jnp.where(jnp.isnan(cands), lam_hi, jnp.clip(cands, 0.0, lam_hi))
-    err = makespan_err(cands)
-    best = jnp.min(err, axis=0)
-    near = err <= best * (1.0 + 1e-6) + tiny
-    lam = jnp.min(jnp.where(near, cands, jnp.inf), axis=0)
+    # i.e. f = (rhok / (3 k3))^(2/5) * (q t_c)^(-1/5), taken in log space.
+    # Factored this way so kappa-scale coefficients never square: alpha^2
+    # ~ 1e-45 underflows f32 (the chip dtype) even though f is representable.
+    f6 = jnp.exp(0.4 * jnp.log(rhok / jnp.maximum(3.0 * k3, tiny))
+                 - 0.2 * jnp.log(jnp.maximum(q * t_c, tiny)))
+    cands = [jnp.zeros_like(t_c),
+             cand_f_clipped(f_min), cand_f_clipped(f_max),
+             cand_s_clipped(s_lo), cand_s_clipped(s_hi),
+             k3 * f6 ** 3]
+    cands = [jnp.where(jnp.isnan(c), lam_hi, jnp.clip(c, 0.0, lam_hi))
+             for c in cands]
+    # candidates stay separate arrays (no stacked axis, which Mosaic would
+    # have to relayout); min/select over them is exact either way
+    errs = [makespan_err(c) for c in cands]
+    best = functools.reduce(jnp.minimum, errs)
+    thresh = best * (1.0 + 1e-6) + tiny
+    lam = functools.reduce(jnp.minimum, [jnp.where(e <= thresh, c, jnp.inf)
+                                         for c, e in zip(cands, errs)])
     # Strictly unattainable deadline (t_c below the q s_lo^2/f_max makespan
     # floor): every candidate ties at the floor, and the min-lambda rule
     # would pick the left edge of the clipped-flat region; the bisection
@@ -102,20 +123,14 @@ def lambda_of_T_linear(T, q, tt, k3, rhok, f_min, f_max, s_lo, s_hi, lam_hi):
                      lam_hi, lam)
 
 
-def _sp1_kernel(T_ref, c_ref, q_ref, tt_ref, out_ref, *, dtype):
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
+def _sp1_kernel(T_ref, c_ref, q_ref, tt_ref, out_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    T = T_ref[...].astype(dtype)              # (M,)
-    q = q_ref[...].astype(dtype)              # (bn,)
-    tt = tt_ref[...].astype(dtype)            # (bn,)
-    lam = lambda_of_T_linear(
-        T[:, None], q[None, :], tt[None, :],
-        c_ref[0], c_ref[1], c_ref[2], c_ref[3], c_ref[4], c_ref[5], c_ref[6])
-    out_ref[...] += jnp.sum(lam, axis=1).astype(out_ref.dtype)
+    k = [c_ref[0, j] for j in range(7)]       # SMEM scalars
+    lam = lambda_of_T_linear(T_ref[...], q_ref[...], tt_ref[...], *k)
+    out_ref[...] += jnp.sum(lam, axis=1, keepdims=True)   # (M, bn) -> (M, 1)
 
 
 def sp1_lambda_sum(T_grid: jax.Array, q: jax.Array, tt: jax.Array,
@@ -123,30 +138,39 @@ def sp1_lambda_sum(T_grid: jax.Array, q: jax.Array, tt: jax.Array,
                    interpret: bool = False,
                    dtype=jnp.float32) -> jax.Array:
     """Sigma_n lambda_n(T) per candidate: T_grid (M,), q/tt (N,),
-    consts (N_CONSTS,) -> (M,). Any N: the tail block is padded with
+    consts (N_CONSTS,) -> (M,). Any N: devices are split into lane blocks of
+    min(block_n, N rounded up to 128), and the tail is padded with
     (q=0, tt=0) lanes, for which every candidate ties at makespan 0 and the
     min-lambda rule returns exactly 0 — an implicit mask of the partial sum.
 
     dtype: in-kernel compute/output dtype, as for `waterfill.waterfill_gprime`.
     """
+    if block_n % _LANES:
+        raise ValueError(f"block_n must be a multiple of {_LANES}, "
+                         f"got {block_n}")
     N = q.shape[0]
-    rem = (-N) % block_n
+    bn = min(block_n, -(-N // _LANES) * _LANES)
+    rem = (-N) % bn
     if rem:
         q = jnp.concatenate([q, jnp.zeros((rem,), q.dtype)])
         tt = jnp.concatenate([tt, jnp.zeros((rem,), tt.dtype)])
         N += rem
     M = T_grid.shape[0]
-    return pl.pallas_call(
-        functools.partial(_sp1_kernel, dtype=dtype),
-        grid=(N // block_n,),
+    out = pl.pallas_call(
+        _sp1_kernel,
+        grid=(N // bn,),
         in_specs=[
-            pl.BlockSpec((M,), lambda i: (0,)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
-            pl.BlockSpec((block_n,), lambda i: (i,)),
+            pl.BlockSpec((M, 1), lambda i: (0, 0)),
+            pl.BlockSpec((1, N_CONSTS), lambda i: (0, 0),
+                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
+            pl.BlockSpec((1, bn), lambda i: (0, i)),
         ],
-        out_specs=pl.BlockSpec((M,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((M,), dtype),
+        out_specs=pl.BlockSpec((M, 1), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((M, 1), dtype),
         interpret=interpret,
-    )(T_grid.astype(dtype), consts.astype(dtype), q.astype(dtype),
-      tt.astype(dtype))
+        name="sp1_lambda_sum",
+    )(T_grid.astype(dtype).reshape(M, 1),
+      consts.astype(dtype).reshape(1, N_CONSTS),
+      q.astype(dtype).reshape(1, N), tt.astype(dtype).reshape(1, N))
+    return out[:, 0]

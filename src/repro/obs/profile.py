@@ -23,9 +23,10 @@ Two tools, both opt-in (nothing here runs on the serving path):
     this module's lowering).
 
 `Compiled.cost_analysis()` is backend-dependent: it may return a list of
-per-computation dicts, a bare dict, or raise on backends without a cost
-model. `record_cost` normalizes all three (returns ``None`` — and records
-nothing — when no cost model is available).
+per-computation dicts, a bare dict, or raise `NotImplementedError` on
+backends without a cost model. `record_cost` normalizes all three (returns
+``None`` — and records nothing — when no cost model is available); an
+error from the compile itself raises.
 """
 from __future__ import annotations
 
@@ -37,7 +38,7 @@ from typing import Any, Dict, Optional
 from . import recorder as _rec
 from .metrics import MetricsRegistry, REGISTRY
 
-__all__ = ["trace", "record_cost", "solve_cost"]
+__all__ = ["trace", "record_cost", "compile_solve", "solve_cost"]
 
 _TRACE_LOCK = threading.Lock()
 _TRACE_ACTIVE = False
@@ -111,16 +112,22 @@ def record_cost(label: str, fn, *args,
 
     Returns the normalized cost dict (always containing ``flops`` and
     ``bytes_accessed`` keys, 0.0 when the backend reports neither), or
-    ``None`` when the backend has no cost model. `fn` may also be an
-    already-jitted function — it is lowered as-is."""
+    ``None`` when the backend has no cost model. A compile error raises.
+    `fn` may also be an already-jitted function — it is lowered as-is."""
     import jax
 
     jitted = fn if hasattr(fn, "lower") else jax.jit(
         fn, static_argnames=static_argnames)
+    return _record_compiled(label, jitted.lower(*args, **kwargs).compile(),
+                            registry)
+
+
+def _record_compiled(label: str, compiled,
+                     registry: Optional[MetricsRegistry]
+                     ) -> Optional[Dict[str, float]]:
     try:
-        compiled = jitted.lower(*args, **kwargs).compile()
         cost = _normalize_cost(compiled.cost_analysis())
-    except Exception:   # no cost model / unsupported backend: degrade
+    except NotImplementedError:   # the backend reports no cost model
         return None
     if cost is None:
         return None
@@ -135,13 +142,12 @@ def record_cost(label: str, fn, *args,
     return out
 
 
-def solve_cost(problem, spec=None,
-               registry: Optional[MetricsRegistry] = None
-               ) -> Optional[Dict[str, float]]:
-    """Cost analysis for the compiled program `solve(problem, spec)` would
-    run, keyed ``solve.<topology>.C<cells>.N<devices>`` (single-cell and
-    unsharded (C, N) fleet topologies; mesh/rounds/assoc problems are out
-    of scope — profile those with `trace`). Never executes the solve."""
+def compile_solve(problem, spec=None):
+    """AOT-compile the program `solve(problem, spec)` runs, without running
+    it. Returns ``(label, compiled)``, the label keyed
+    ``solve.<topology>.C<cells>.N<devices>``. Single-cell and unsharded
+    (C, N) fleet topologies only; mesh/rounds/assoc problems are out of
+    scope — profile those with `trace`."""
     import jax
     import jax.numpy as jnp
 
@@ -151,12 +157,13 @@ def solve_cost(problem, spec=None,
     from repro.core.accuracy import default_accuracy
     from repro.core.bcd import (_allocate_impl, _fleet_cell_fn,
                                 _init_carry_state, initial_allocation)
+    from repro.kernels.ops import kernel_mode
 
     spec = SolverSpec() if spec is None else spec
     topo = _topology_label(problem)
     if topo not in ("bcd", "bcd_fleet"):
         raise ValueError(
-            f"solve_cost: only single-cell and fleet topologies are "
+            f"compile_solve: only single-cell and fleet topologies are "
             f"supported, got {topo!r}")
     sysp, init = _apply_dtype(problem.system, problem.init, spec.dtype)
     acc = problem.acc if problem.acc is not None else default_accuracy()
@@ -165,18 +172,27 @@ def solve_cost(problem, spec=None,
         alloc0 = init if init is not None else initial_allocation(sysp)
         state0 = _init_carry_state(sysp, alloc0)
         warr = weights_leaf(problem.weights, state0[0].dtype)
-        label = f"solve.bcd.N{gain.shape[0]}"
-        cost = record_cost(
-            label, _allocate_impl, sysp, warr, acc, state0,
-            spec.max_iters, spec.tol, spec.sp1_method, spec.sp2_method,
-            spec.sp2_iters, registry=registry)
-        return cost
+        compiled = _allocate_impl.lower(
+            sysp, warr, acc, state0, spec.max_iters, spec.tol,
+            spec.sp1_method, spec.sp2_method, spec.sp2_iters,
+            kernel_mode()).compile()
+        return f"solve.bcd.N{gain.shape[0]}", compiled
     C, N = int(gain.shape[0]), int(gain.shape[1])
     warr = weights_leaf(problem.weights, gain.dtype, cells=C)
     fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
-                        spec.sp2_method, spec.sp2_iters,
+                        spec.sp2_method, spec.sp2_iters, kernel_mode(),
                         with_init=init is not None)
-    vf = jax.jit(jax.vmap(fn))
-    label = f"solve.fleet.C{C}.N{N}"
     args = (sysp, warr) if init is None else (sysp, warr, init)
-    return record_cost(label, vf, *args, registry=registry)
+    compiled = jax.jit(jax.vmap(fn)).lower(*args).compile()
+    return f"solve.fleet.C{C}.N{N}", compiled
+
+
+def solve_cost(problem, spec=None,
+               registry: Optional[MetricsRegistry] = None
+               ) -> Optional[Dict[str, float]]:
+    """Cost analysis for the compiled program `solve(problem, spec)` would
+    run (see `compile_solve` for the label and the topologies it covers).
+    Never executes the solve; ``None`` when the backend has no cost
+    model."""
+    label, compiled = compile_solve(problem, spec)
+    return _record_compiled(label, compiled, registry)

@@ -29,7 +29,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.accuracy import AccuracyModel
@@ -144,23 +143,23 @@ class RegionResult:
 
 
 @partial(jax.jit, static_argnames=("acc", "max_iters", "sp1_method",
-                                   "sp2_method", "sp2_iters", "mesh",
-                                   "lockstep", "with_init"))
+                                   "sp2_method", "sp2_iters", "kernel",
+                                   "mesh", "lockstep", "with_init"))
 def _region_solve_impl(sys_batch, warr, init, tol, acc: AccuracyModel,
                        max_iters: int, sp1_method: str, sp2_method: str,
-                       sp2_iters: int, mesh: Mesh, lockstep: bool,
-                       with_init: bool):
+                       sp2_iters: int, kernel: str, mesh: Mesh,
+                       lockstep: bool, with_init: bool):
     """warr is the (C, 3) per-cell weights stack — a traced, cell-sharded
     operand, so mixed per-cell weights share this one jit cache entry."""
     fn = _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
-                        sp2_iters, with_init)
+                        sp2_iters, kernel, with_init)
     vf = jax.vmap(fn)
     args = (sys_batch, warr, init) if with_init else (sys_batch, warr)
     if lockstep or mesh.devices.size == 1:
         return vf(*args)
     in_specs = tuple(cell_specs(a) for a in args)
-    return shard_map(vf, mesh=mesh, in_specs=in_specs,
-                     out_specs=P("cells"), check_rep=False)(*args)
+    return jax.shard_map(vf, mesh=mesh, in_specs=in_specs,
+                         out_specs=P("cells"), check_vma=False)(*args)
 
 
 @partial(jax.jit, static_argnames=("acc", "max_iters", "sp2_method",
@@ -180,8 +179,8 @@ def _region_fixed_impl(sys_batch, warr, T_round, alloc0, tol,
     if lockstep or mesh.devices.size == 1:
         return vf(*args)
     in_specs = tuple(cell_specs(a) for a in args)
-    return shard_map(vf, mesh=mesh, in_specs=in_specs,
-                     out_specs=P("cells"), check_rep=False)(*args)
+    return jax.shard_map(vf, mesh=mesh, in_specs=in_specs,
+                         out_specs=P("cells"), check_vma=False)(*args)
 
 
 def _pack_stats(fleet: FleetResult, n_shards: int = 1) -> Array:
@@ -291,10 +290,11 @@ def run_rounds_region(key: jax.Array, sys_batch: SystemParams, w: Weights,
                  SolverSpec(lockstep=lockstep))
 
 
-@partial(jax.jit, static_argnames=("acc", "cfg", "mesh", "lockstep",
-                                   "with_init"))
+@partial(jax.jit, static_argnames=("acc", "cfg", "kernel", "mesh",
+                                   "lockstep", "with_init"))
 def _region_rounds_impl(sys_batch, warr, keys, init_state, acc, cfg,
-                        mesh: Mesh, lockstep: bool, with_init: bool):
+                        kernel: str, mesh: Mesh, lockstep: bool,
+                        with_init: bool):
     """warr is the (C, 3) per-cell weights stack (traced, cell-sharded)."""
     from repro.dynamics.engine import (_cell_engine, _init_carry_state,
                                        initial_allocation)
@@ -302,12 +302,12 @@ def _region_rounds_impl(sys_batch, warr, keys, init_state, acc, cfg,
     def one(sysc, warr_c, kc, *st):
         st0 = st[0] if with_init else _init_carry_state(
             sysc, initial_allocation(sysc))
-        return _cell_engine(sysc, warr_c, acc, kc, st0, cfg)
+        return _cell_engine(sysc, warr_c, acc, kc, st0, cfg, kernel)
 
     vf = jax.vmap(one)
     args = (sys_batch, warr, keys) + ((init_state,) if with_init else ())
     if lockstep or mesh.devices.size == 1:
         return vf(*args)
     in_specs = tuple(cell_specs(a) for a in args)
-    return shard_map(vf, mesh=mesh, in_specs=in_specs,
-                     out_specs=P("cells"), check_rep=False)(*args)
+    return jax.shard_map(vf, mesh=mesh, in_specs=in_specs,
+                         out_specs=P("cells"), check_vma=False)(*args)

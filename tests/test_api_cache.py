@@ -101,3 +101,25 @@ def test_single_cell_weight_changes_do_not_recompile(compile_counter):
                       weights=Weights(0.1 + 0.2 * i, 0.9 - 0.2 * i,
                                       float(i))), spec)
     assert compile_counter.count == before
+
+
+def test_kernel_mode_is_part_of_the_jit_key(compile_counter, monkeypatch):
+    """The SP1 kernel's mode is resolved outside jit and reaches the solve's
+    jit key: asking for interpret mode after a ref-mode solve builds a new
+    program instead of reusing the cached ref one, with the same answer."""
+    from repro.kernels.ops import kernel_mode
+
+    problem = Problem(system=make_system(jax.random.PRNGKey(3), n_devices=12),
+                      weights=Weights(0.5, 0.5, 1.0))
+    spec = SolverSpec(max_iters=6, tol=1e-8)
+    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+    assert kernel_mode() == "ref"            # the CPU default
+    ref = solve(problem, spec)
+    before = compile_counter.count
+    solve(problem, spec)
+    assert compile_counter.count == before
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    assert kernel_mode() == "interpret"
+    interp = solve(problem, spec)
+    assert compile_counter.count > before
+    np.testing.assert_allclose(interp.objective, ref.objective, rtol=1e-10)

@@ -103,12 +103,28 @@ def test_waterfill_sweep(N, block):
     j = jnp.abs(jax.random.normal(key, (N,))) * 1e-3 + 1e-5
     rmin = jnp.abs(jax.random.normal(jax.random.fold_in(key, 1), (N,))) * 1e5
     mu = jnp.logspace(-6, 1, 16)
-    # impl="pallas" keeps the kernel body under test ("auto" routes to the
+    # impl="interpret" keeps the kernel body under test ("auto" routes to the
     # ref oracle on CPU, which would compare the oracle against itself)
-    g1 = ops.waterfill_gprime(mu, j, rmin, 20e6, block_n=block, impl="pallas")
+    g1 = ops.waterfill_gprime(mu, j, rmin, 20e6, block_n=block,
+                              impl="interpret")
     g2 = ref.waterfill_gprime_ref(mu, j, rmin, 20e6)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=1e-4,
                                atol=1.0)
+
+
+def test_kernel_mode_resolution(monkeypatch):
+    """"auto" is the ref oracle off a TPU unless interpret mode is asked
+    for; an explicit mode is kept as given; anything else is an error."""
+    monkeypatch.delenv("REPRO_FORCE_INTERPRET", raising=False)
+    assert ops.kernel_mode() == ("mosaic" if jax.default_backend() == "tpu"
+                                 else "ref")
+    for mode in ops.KERNEL_MODES:
+        assert ops.kernel_mode(mode) == mode
+    monkeypatch.setenv("REPRO_FORCE_INTERPRET", "1")
+    assert ops.kernel_mode() == "interpret"
+    assert ops.kernel_mode("mosaic") == "mosaic"
+    with pytest.raises(ValueError, match="impl"):
+        ops.kernel_mode("pallas")
 
 
 def test_model_chunked_attention_matches_ref():

@@ -276,3 +276,68 @@ def test_sp1_sweep_ops_entry_matches_bisection_sum():
                        for i in range(T_grid.shape[0])])
     np.testing.assert_allclose(np.asarray(s_op), np.asarray(s_bis),
                                rtol=1e-5, atol=1e-7 * lam_hi)
+
+
+@pytest.mark.parametrize("cells,N", [(1, 64), (16, 300), (3, 1500)])
+def test_sp1_sweep_vmapped_cells_match_ref(cells, N):
+    """Under vmap (the fleet/region/rounds solves) every cell carries its
+    own T-grid, coefficients and devices; the kernel body must agree with
+    the vmapped oracle cell by cell, padded tails included."""
+    rows = [_sweep_inputs(seed=20 + c, n=N,
+                          w=((0.2, 0.8, 1.0), (0.5, 0.5, 10.0),
+                             (0.9, 0.1, 1.0))[c % 3])
+            for c in range(cells)]
+    T_grid, q, tt, consts = (jnp.stack(x) for x in zip(*rows))
+    s_pal = jax.vmap(lambda *a: sp1_lambda_sum(
+        *a, interpret=True, dtype=jnp.float64))(T_grid, q, tt, consts)
+    s_ref = jax.vmap(sp1_lambda_sum_ref)(T_grid, q, tt, consts)
+    assert s_pal.shape == (cells, T_grid.shape[1])
+    np.testing.assert_allclose(np.asarray(s_pal), np.asarray(s_ref),
+                               rtol=1e-12)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+def test_cbrt_nonneg_matches_cbrt(dtype):
+    """The exp/log cube root the kernel uses (Mosaic has no cbrt) is within
+    2 ulps of the true cube root (numpy's, in f64), exactly 0 at 0 and inf
+    at inf."""
+    from repro.kernels.sp1_sweep import _cbrt_nonneg
+
+    fi = jnp.finfo(dtype)
+    x = jnp.concatenate([
+        jnp.geomspace(float(fi.tiny), float(fi.max) / 2, 4001, dtype=dtype),
+        jnp.asarray([0.0, 1.0, 8.0, 27.0, jnp.inf], dtype)])
+    got = np.asarray(_cbrt_nonneg(x), np.float64)
+    want = np.cbrt(np.asarray(x, np.float64))
+    assert got[-5] == 0.0 and got[-1] == np.inf
+    assert list(got[-4:-1]) == [1.0, 2.0, 3.0]
+    finite = np.isfinite(want) & (want > 0)
+    np.testing.assert_allclose(got[finite], want[finite],
+                               rtol=2 * float(fi.eps))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+def test_sweep_edge_lanes_exact(dtype):
+    """Pad lanes (q = 0, tt = 0) add exactly 0 to every candidate deadline,
+    and the pure-latency weighting (k3 = 0, so lam = 0 puts f at its clip)
+    stays finite — through the kernel body and the oracle alike."""
+    T_grid, q, tt, consts = _sweep_inputs(seed=7, n=40)
+    T_grid, q, tt = (x.astype(dtype) for x in (T_grid, q, tt))
+    zeros = jnp.zeros((88,), dtype)
+    for k3 in (float(consts[0]), 0.0):
+        c = consts.at[0].set(k3).astype(dtype)
+        lam = lambda_of_T_linear(T_grid[:, None], q[None, :], tt[None, :],
+                                 *c[:7])
+        lam_pad = lambda_of_T_linear(
+            T_grid[:, None], jnp.concatenate([q, zeros])[None, :],
+            jnp.concatenate([tt, zeros])[None, :], *c[:7])
+        assert np.isfinite(np.asarray(lam)).all()
+        np.testing.assert_array_equal(np.asarray(lam_pad[:, :40]),
+                                      np.asarray(lam))
+        assert np.all(np.asarray(lam_pad[:, 40:]) == 0.0)
+        s_ref = sp1_lambda_sum_ref(T_grid, q, tt, c)
+        s_pal = sp1_lambda_sum(T_grid, q, tt, c, interpret=True, dtype=dtype)
+        assert np.isfinite(np.asarray(s_pal)).all()
+        np.testing.assert_allclose(np.asarray(s_pal), np.asarray(s_ref),
+                                   rtol=1e-12 if dtype == jnp.float64
+                                   else 1e-5)
