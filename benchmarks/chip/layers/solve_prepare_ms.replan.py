@@ -1,0 +1,15 @@
+"""Host time of `solve()`'s `prepare` stage per re-plan (ms, mean): the self
+time of the program's `repro.solve.prepare` spans in the traced part of
+the window, on the device trace's clock. The stage is everything before
+the compiled solve is called: validation, dtype casts, the weights
+operand, padding and placement on the mesh."""
+from harness import named
+
+named.install()   # before the run: the trace is gone when `read` is called
+
+
+def read(run):
+    n = named.of(run)
+    got = n.spans.get("repro.solve.prepare") if run.kind == "replan" and n \
+        else None
+    return 1e3 * got[1] / got[0] if got else None

@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.accuracy import default_accuracy
 from repro.core.bcd import (_FIXED_COLS, _LEDGER_COLS, _allocate_fixed_impl,
                             _allocate_impl, _fleet_cell_fn, _fleet_result,
@@ -98,14 +99,14 @@ def _apply_dtype(system: SystemParams, init: Optional[Allocation],
 
 def _topology_label(problem: Problem) -> str:
     """Deterministic topology tag for the solve span (shape metadata only —
-    reading `.ndim` never syncs the device)."""
+    `np.ndim` reads `.ndim` and never syncs or copies to the device)."""
     if problem.assoc is not None:
         return "assoc"
     base = ("rounds" if problem.rounds is not None
             else "fixed" if problem.deadline is not None else "bcd")
     if problem.mesh is not None:
         return base + "_region"
-    if jnp.asarray(problem.system.gain).ndim == 2:
+    if np.ndim(problem.system.gain) == 2:
         return base + "_fleet"
     return base
 
@@ -117,20 +118,45 @@ def solve(problem: Problem, spec: Optional[SolverSpec] = None):
     `RegionResult`, or `RoundsResult`) — bit-identical to the legacy entry
     point it replaces (parity-tested in tests/test_api_parity.py).
 
-    When a `repro.obs` recorder is enabled the whole call is wrapped in a
-    `solve` span tagged with the routed topology; with the default no-op
-    recorder this is one predicate check (see tests/test_obs.py for the
-    jit-cache guard: the span changes no compiled shapes either way).
+    The call is a `solve` span (tagged with the routed topology for a
+    recorder) whose three children split its host time: `solve.prepare`
+    (validation, dtype casts, weights, padding and placement),
+    `solve.launch` (tracing and enqueueing the compiled solve) and
+    `solve.result` (assembling the result). With no recorder and no
+    profiler session each is the cached null span (see tests/test_obs.py
+    for the jit-cache guard: the spans change no compiled shapes).
     """
-    from repro import obs
-
-    if not obs.enabled():
-        return _solve_routed(problem, spec)
-    with obs.span("solve", topology=_topology_label(problem)):
-        return _solve_routed(problem, spec)
+    attrs = {"topology": _topology_label(problem)} if obs.enabled() else {}
+    with obs.span("solve", **attrs), _Stages() as stages:
+        return _solve_routed(problem, spec, stages)
 
 
-def _solve_routed(problem: Problem, spec: Optional[SolverSpec]):
+class _Stages:
+    """The child spans of one `solve` call, one open at a time: `to(name)`
+    closes the open one and opens `solve.<name>`; leaving the `with`
+    closes the last."""
+
+    __slots__ = ("_open",)
+
+    def __enter__(self):
+        self._open = None
+        return self
+
+    def to(self, name: str) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+        self._open = obs.span("solve." + name)
+        self._open.__enter__()
+
+    def __exit__(self, *exc):
+        if self._open is not None:
+            self._open.__exit__(*exc)
+        return False
+
+
+def _solve_routed(problem: Problem, spec: Optional[SolverSpec],
+                  stages: _Stages):
+    stages.to("prepare")
     spec = SolverSpec() if spec is None else spec
     cells = problem.cells   # also validates system.gain is 1-D or 2-D
     sysp, init = _apply_dtype(problem.system, problem.init, spec.dtype)
@@ -152,6 +178,8 @@ def _solve_routed(problem: Problem, spec: Optional[SolverSpec]):
             raise ValueError(
                 "solve: assoc requires a stacked (C, N) cross-cell system "
                 "(assoc.make_multicell)")
+        # the association loop launches and reads back its own solves
+        stages.to("launch")
         return solve_assoc(
             dataclasses.replace(problem, system=sysp, init=init), spec)
     if problem.rounds is not None:
@@ -176,27 +204,27 @@ def _solve_routed(problem: Problem, spec: Optional[SolverSpec]):
             if cells is None:
                 raise ValueError("solve: mesh requires a stacked (C, N) "
                                  "system (stack_systems / make_fleet)")
-            return _solve_rounds_region(problem, spec, sysp, init)
+            return _solve_rounds_region(problem, spec, sysp, init, stages)
         if cells is None:
-            return _solve_rounds(problem, spec, sysp, init)
-        return _solve_rounds_fleet(problem, spec, sysp, init)
+            return _solve_rounds(problem, spec, sysp, init, stages)
+        return _solve_rounds_fleet(problem, spec, sysp, init, stages)
     if problem.deadline is not None:
         if problem.mesh is not None:
             if cells is None:
                 raise ValueError("solve: mesh requires a stacked (C, N) "
                                  "system (stack_systems / make_fleet)")
-            return _solve_fixed_region(problem, spec, sysp, init)
+            return _solve_fixed_region(problem, spec, sysp, init, stages)
         if cells is not None:
-            return _solve_fixed_fleet(problem, spec, sysp, init)
-        return _solve_fixed(problem, spec, sysp, init)
+            return _solve_fixed_fleet(problem, spec, sysp, init, stages)
+        return _solve_fixed(problem, spec, sysp, init, stages)
     if problem.mesh is not None:
         if cells is None:
             raise ValueError("solve: mesh requires a stacked (C, N) system "
                              "(stack_systems / make_fleet)")
-        return _solve_region(problem, spec, sysp, init)
+        return _solve_region(problem, spec, sysp, init, stages)
     if cells is None:
-        return _solve_single(problem, spec, sysp, init)
-    return _solve_fleet(problem, spec, sysp, init)
+        return _solve_single(problem, spec, sysp, init, stages)
+    return _solve_fleet(problem, spec, sysp, init, stages)
 
 
 # ---------------------------------------------------------------------------
@@ -228,19 +256,23 @@ def _bcd_result(out, alloc0, spec: SolverSpec, cols, objective_col: str,
                      counters=SolveCounters(data=counters))
 
 
-def _solve_single(p: Problem, spec: SolverSpec, sysp, init) -> BCDResult:
+def _solve_single(p: Problem, spec: SolverSpec, sysp, init,
+                  stages: _Stages) -> BCDResult:
     acc = p.acc if p.acc is not None else default_accuracy()
     alloc0 = init if init is not None else initial_allocation(sysp)
     state0 = _init_carry_state(sysp, alloc0)
     warr = weights_leaf(p.weights, state0[0].dtype)
+    stages.to("launch")
     out = _allocate_impl(
         sysp, warr, acc, state0, spec.max_iters, spec.tol,
         spec.sp1_method, spec.sp2_method, spec.sp2_iters, kernel_mode())
+    stages.to("result")
     return _bcd_result(out, alloc0, spec, _LEDGER_COLS, "objective",
                        with_s_relaxed=True)
 
 
-def _solve_fixed(p: Problem, spec: SolverSpec, sysp, init) -> BCDResult:
+def _solve_fixed(p: Problem, spec: SolverSpec, sysp, init,
+                 stages: _Stages) -> BCDResult:
     acc = p.acc if p.acc is not None else default_accuracy()
     T_round = p.deadline / sysp.global_rounds
     alloc0 = init if init is not None else initial_allocation(
@@ -248,14 +280,18 @@ def _solve_fixed(p: Problem, spec: SolverSpec, sysp, init) -> BCDResult:
     state0 = _init_carry_state(sysp, alloc0)
     dtype = state0[0].dtype
     warr = weights_leaf(p.weights, dtype)
+    T_round = jnp.asarray(T_round, dtype)
+    stages.to("launch")
     out = _allocate_fixed_impl(
-        sysp, warr, acc, jnp.asarray(T_round, dtype), state0,
+        sysp, warr, acc, T_round, state0,
         spec.max_iters, spec.tol, spec.sp2_method, spec.sp2_iters)
+    stages.to("result")
     return _bcd_result(out, alloc0, spec, _FIXED_COLS, "energy",
                        with_s_relaxed=False)
 
 
-def _solve_fixed_fleet(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_fixed_fleet(p: Problem, spec: SolverSpec, sysp, init,
+                       stages: _Stages):
     """Deadline-constrained BCD vmapped over a stacked (C, N) fleet.
 
     `Problem.deadline` may be a scalar (one total budget for every cell)
@@ -276,7 +312,9 @@ def _solve_fixed_fleet(p: Problem, spec: SolverSpec, sysp, init):
             sysc, bandwidth_frac=p.bandwidth_frac))(sysp)
     fn = _fleet_fixed_cell_fn(acc, spec.max_iters, spec.tol,
                               spec.sp2_method, spec.sp2_iters)
+    stages.to("launch")
     out = jax.vmap(fn)(sysp, warr, T_round, alloc0)
+    stages.to("result")
     return _fleet_result(out, spec.max_iters, dtype, cols=_FIXED_COLS)
 
 
@@ -293,7 +331,8 @@ def _per_cell_T_round(p: Problem, sysp, C: int, dtype):
         / jnp.asarray(sysp.global_rounds, dtype)
 
 
-def _solve_fixed_region(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_fixed_region(p: Problem, spec: SolverSpec, sysp, init,
+                        stages: _Stages):
     """Deadline-constrained fleet solve sharded over `Problem.mesh`: the
     vmapped `_fleet_fixed_cell_fn` under the region shard_map, exactly the
     free-variant `_solve_region` layout — pad the cell axis to a mesh
@@ -320,10 +359,12 @@ def _solve_fixed_region(p: Problem, spec: SolverSpec, sysp, init):
                                  Cp), mesh)
     T_b = place_cells(pad_cells(T_round, Cp), mesh)
     alloc0b = place_cells(pad_cells(alloc0, Cp), mesh)
-    out = _region_fixed_impl(sysb, warr, T_b, alloc0b,
-                             jnp.asarray(spec.tol, dtype), acc,
+    tol = jnp.asarray(spec.tol, dtype)
+    stages.to("launch")
+    out = _region_fixed_impl(sysb, warr, T_b, alloc0b, tol, acc,
                              spec.max_iters, spec.sp2_method, spec.sp2_iters,
                              mesh, spec.lockstep)
+    stages.to("result")
     fleet = _slice_fleet(
         _fleet_result(out, spec.max_iters, dtype, cols=_FIXED_COLS), C)
     return RegionResult(fleet=fleet,
@@ -331,7 +372,8 @@ def _solve_fixed_region(p: Problem, spec: SolverSpec, sysp, init):
                         _n_cells=C, _mesh_devices=D)
 
 
-def _solve_fleet(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_fleet(p: Problem, spec: SolverSpec, sysp, init,
+                 stages: _Stages):
     acc = p.acc if p.acc is not None else default_accuracy()
     dtype = jnp.asarray(sysp.gain).dtype
     C = int(jnp.asarray(sysp.gain).shape[0])
@@ -339,12 +381,15 @@ def _solve_fleet(p: Problem, spec: SolverSpec, sysp, init):
     fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
                         spec.sp2_method, spec.sp2_iters, kernel_mode(),
                         with_init=init is not None)
+    stages.to("launch")
     out = jax.vmap(fn)(sysp, warr) if init is None \
         else jax.vmap(fn)(sysp, warr, init)
+    stages.to("result")
     return _fleet_result(out, spec.max_iters, dtype)
 
 
-def _solve_region(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_region(p: Problem, spec: SolverSpec, sysp, init,
+                  stages: _Stages):
     from repro.region.mesh import (RegionResult, _pack_stats,
                                    _region_solve_impl, _slice_fleet,
                                    pad_cells, place_cells)
@@ -359,17 +404,21 @@ def _solve_region(p: Problem, spec: SolverSpec, sysp, init):
     initb = None if init is None else place_cells(pad_cells(init, Cp), mesh)
     warr = place_cells(pad_cells(weights_leaf(p.weights, dtype, cells=C),
                                  Cp), mesh)
-    out = _region_solve_impl(sysb, warr, initb, jnp.asarray(spec.tol, dtype),
+    tol = jnp.asarray(spec.tol, dtype)
+    stages.to("launch")
+    out = _region_solve_impl(sysb, warr, initb, tol,
                              acc, spec.max_iters, spec.sp1_method,
                              spec.sp2_method, spec.sp2_iters, kernel_mode(),
                              mesh, spec.lockstep, init is not None)
+    stages.to("result")
     fleet = _slice_fleet(_fleet_result(out, spec.max_iters, dtype), C)
     return RegionResult(fleet=fleet,
                         _stats_packed=_pack_stats(fleet, n_shards=D),
                         _n_cells=C, _mesh_devices=D)
 
 
-def _solve_rounds(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_rounds(p: Problem, spec: SolverSpec, sysp, init,
+                  stages: _Stages):
     from repro.dynamics.engine import (_check_simulation_init, _result,
                                        _run_rounds_impl)
 
@@ -379,11 +428,15 @@ def _solve_rounds(p: Problem, spec: SolverSpec, sysp, init):
     alloc0 = init if init is not None else initial_allocation(sysp)
     state0 = _init_carry_state(sysp, alloc0)
     warr = weights_leaf(p.weights, state0[0].dtype)
-    return _result(_run_rounds_impl(sysp, warr, acc, p.key, state0, cfg,
-                                    kernel_mode()))
+    stages.to("launch")
+    out = _run_rounds_impl(sysp, warr, acc, p.key, state0, cfg,
+                           kernel_mode())
+    stages.to("result")
+    return _result(out)
 
 
-def _solve_rounds_fleet(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_rounds_fleet(p: Problem, spec: SolverSpec, sysp, init,
+                        stages: _Stages):
     from repro.dynamics.engine import (_check_simulation_init, _result,
                                        _run_rounds_fleet_impl)
 
@@ -396,11 +449,15 @@ def _solve_rounds_fleet(p: Problem, spec: SolverSpec, sysp, init):
     keys = jax.random.split(p.key, C)
     init_state = None if init is None else jax.vmap(_init_carry_state)(
         sysp, init)
-    return _result(_run_rounds_fleet_impl(sysp, warr, acc, keys, init_state,
-                                          cfg, kernel_mode()))
+    stages.to("launch")
+    out = _run_rounds_fleet_impl(sysp, warr, acc, keys, init_state, cfg,
+                                 kernel_mode())
+    stages.to("result")
+    return _result(out)
 
 
-def _solve_rounds_region(p: Problem, spec: SolverSpec, sysp, init):
+def _solve_rounds_region(p: Problem, spec: SolverSpec, sysp, init,
+                         stages: _Stages):
     from repro.dynamics.config import RoundsResult
     from repro.dynamics.engine import _check_simulation_init, _result
     from repro.region.mesh import (_region_rounds_impl, pad_cells,
@@ -423,9 +480,11 @@ def _solve_rounds_region(p: Problem, spec: SolverSpec, sysp, init):
         sysp, init)
     initb = None if init_state is None else place_cells(
         pad_cells(init_state, Cp), mesh)
+    stages.to("launch")
     out = _region_rounds_impl(sysb, warr, keysb, initb, acc, cfg,
                               kernel_mode(), mesh, spec.lockstep,
                               init_state is not None)
+    stages.to("result")
     res = _result(out)
     cut = lambda x: x[:C]
     return RoundsResult(

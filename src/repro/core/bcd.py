@@ -14,6 +14,12 @@ drivers live behind the unified entry point `repro.solve(Problem, SolverSpec)`
 shims over it — same results, bit-identical, one `DeprecationWarning` per
 process. Objective weights are a traced `(3,)` (per cell) operand of
 `_allocate_impl`, never part of the jit-cache key.
+
+Both impls name their blocks with `jax.named_scope`: the loop `bcd`, the
+resolution/frequency block `sp1` and the power/bandwidth block `sp2`. The
+names exist only at trace time and ride into every compiled op's name
+stack (the `tf_op` of a device trace), on every path that reaches the
+impls: fleet vmap, region shard_map, pipeline batches, rounds and `diff`.
 """
 from __future__ import annotations
 
@@ -260,19 +266,22 @@ def _allocate_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
     def step(state):
         B, p, _, _, _, _ = state
         tt = sys.bits / jnp.maximum(_rate(sys, B, p), 1e-12)
-        f, s, s_hat, T = solve_sp1(sys, warr_sp1, acc, tt)
-        rmin = r_min(sys, f, s, T)
-        if sp2_method == "direct":
-            # sp2_iters ledger column = measured dE/dB eval count of the
-            # carried-bracket dual search (vs `sp2.direct_eval_counts`)
-            p_new, B_new, ev = _sp2_direct_impl(sys, rmin)
-            sp2_it = ev.astype(dtype)
-            sp2_res = jnp.zeros((), dtype)
-        else:
-            p_new, B_new, _, _, it2, res2 = _sp2_jong_core(
-                sys, warr[0], rmin, p, B, max_iters=sp2_iters)
-            sp2_it = it2.astype(dtype)
-            sp2_res = res2.astype(dtype)
+        with jax.named_scope("sp1"):
+            f, s, s_hat, T = solve_sp1(sys, warr_sp1, acc, tt)
+        with jax.named_scope("sp2"):
+            rmin = r_min(sys, f, s, T)
+            if sp2_method == "direct":
+                # sp2_iters ledger column = measured dE/dB eval count of
+                # the carried-bracket dual search (vs
+                # `sp2.direct_eval_counts`)
+                p_new, B_new, ev = _sp2_direct_impl(sys, rmin)
+                sp2_it = ev.astype(dtype)
+                sp2_res = jnp.zeros((), dtype)
+            else:
+                p_new, B_new, _, _, it2, res2 = _sp2_jong_core(
+                    sys, warr[0], rmin, p, B, max_iters=sp2_iters)
+                sp2_it = it2.astype(dtype)
+                sp2_res = res2.astype(dtype)
         w = Weights(warr[0], warr[1], warr[2])
         alloc = Allocation(bandwidth=B_new, power=p_new, freq=f, resolution=s,
                            s_relaxed=s_hat, T=T)
@@ -283,8 +292,9 @@ def _allocate_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
                    sp2_it, sp2_res)
         return (B_new, p_new, f, s, s_hat, T), metrics
 
-    out = _bcd_while(state0, max_iters, len(_LEDGER_COLS), tol, step,
-                     mask=sys.active)
+    with jax.named_scope("bcd"):
+        out = _bcd_while(state0, max_iters, len(_LEDGER_COLS), tol, step,
+                         mask=sys.active)
     counters = _pack_counters(out[6], out[8], max_iters,
                               _LEDGER_COLS.index("sp2_iters"),
                               _LEDGER_COLS.index("rel_step"),
@@ -366,21 +376,24 @@ def _allocate_fixed_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
     def step(state):
         B, p, _, _, s_hat, _ = state
         tt = sys.bits / jnp.maximum(_rate(sys, B, p), 1e-12)
-        f, s = _solve_sp1_fixed_impl(sys, warr, acc, tt, T_round)
-        # Break the BCD split deadlock: with a hard deadline, SP1 pins
-        # t_cmp = T - t_trans(current p, B), so SP2's rate floor equals the
-        # current rate and (p, B) can never move. Re-derive the floor from the
-        # per-device OPTIMAL compute/transmit split (convex in t_trans:
-        # E_cmp = kappa cyc^3/(T-tt)^2 rises, E_trans falls; golden section).
-        tt_opt = _optimal_split(sys, s, B, T_round)
-        rmin = sys.bits / tt_opt
-        if sp2_method == "direct":
-            p_new, B_new, ev = _sp2_direct_impl(sys, rmin)
-            sp2_ev = ev.astype(dtype)
-        else:
-            p_new, B_new, _, _, it2, _ = _sp2_jong_core(
-                sys, warr[0], rmin, p, B, max_iters=sp2_iters)
-            sp2_ev = it2.astype(dtype)
+        with jax.named_scope("sp1"):
+            f, s = _solve_sp1_fixed_impl(sys, warr, acc, tt, T_round)
+        with jax.named_scope("sp2"):
+            # Break the BCD split deadlock: with a hard deadline, SP1 pins
+            # t_cmp = T - t_trans(current p, B), so SP2's rate floor equals
+            # the current rate and (p, B) can never move. Re-derive the
+            # floor from the per-device OPTIMAL compute/transmit split
+            # (convex in t_trans: E_cmp = kappa cyc^3/(T-tt)^2 rises,
+            # E_trans falls; golden section).
+            tt_opt = _optimal_split(sys, s, B, T_round)
+            rmin = sys.bits / tt_opt
+            if sp2_method == "direct":
+                p_new, B_new, ev = _sp2_direct_impl(sys, rmin)
+                sp2_ev = ev.astype(dtype)
+            else:
+                p_new, B_new, _, _, it2, _ = _sp2_jong_core(
+                    sys, warr[0], rmin, p, B, max_iters=sp2_iters)
+                sp2_ev = it2.astype(dtype)
         # recompute f against the achieved transmission time
         tt_new = sys.bits / jnp.maximum(_rate(sys, B_new, p_new), 1e-12)
         cyc = sys.local_iters * sys.zeta * s ** 2 * sys.cycles * sys.samples
@@ -397,8 +410,9 @@ def _allocate_fixed_impl(sys: SystemParams, warr: Array, acc: AccuracyModel,
 
     # sp1_per_iter = 0: _solve_sp1_fixed_impl enumerates the discrete
     # resolution menu in closed form — no dual search to count
-    out = _bcd_while(state0, max_iters, len(_FIXED_COLS), tol, step,
-                     mask=sys.active)
+    with jax.named_scope("bcd"):
+        out = _bcd_while(state0, max_iters, len(_FIXED_COLS), tol, step,
+                         mask=sys.active)
     counters = _pack_counters(out[6], out[8], max_iters,
                               _FIXED_COLS.index("sp2_evals"),
                               _FIXED_COLS.index("rel_step"), 0)

@@ -4,9 +4,9 @@ Two tools, both opt-in (nothing here runs on the serving path):
 
   * `trace(logdir, label=...)` — a context manager around
     `jax.profiler.start_trace`/`stop_trace`, span-keyed: the session is
-    wrapped in an `obs.span("profile", label=...)`, so device work done
-    inside shows up under the enclosing span names (`recorder._Span`
-    already enters `TraceAnnotation` per span). One session at a time —
+    wrapped in an `obs.span("profile", label=...)`; inside the session
+    every `obs.span` writes a `repro.<name>` host event, so host work
+    shows up under the program's span names. One session at a time —
     a nested `trace` is a no-op yielding ``None`` (JAX raises on double
     start; serving loops shouldn't). The session's wall time lands in
     the `profiler_trace_seconds{label=...}` gauge and each completed

@@ -7,17 +7,22 @@ Both are emitted to the installed `Recorder` as plain dicts — one JSON
 object per event in the JSONL sinks — and are attributed to the enclosing
 span through deterministic integer ids.
 
-The default recorder is `NOOP`: `span()` then returns one cached null
-context manager and `point()` returns immediately, so instrumented hot
-paths cost a single global load + attribute check per site (benchmarked
-as the `obs_overhead.*` BENCH rows; asserted < 2% of serve req/s).
+A span has two sinks: the installed `Recorder`, and a JAX profiler
+session. Whenever a profiler session is collecting (`jax.profiler.
+start_trace`, `obs.profile.trace`, a benchmark's traced window), a span
+also writes a `jax.profiler.TraceAnnotation("repro.<name>")` host event,
+recorder or not, so the program's own spans lie on the device trace's
+clock and can name what the host did while the device sat idle. With
+the default `NOOP` recorder and no session, `span()` returns one cached
+null context manager and `point()` returns immediately: an instrumented
+site costs a global load, an attribute check and the profiler's own
+enabled check (tens of nanoseconds; asserted < 2% of serve wall time).
 
-When a recorder IS enabled, spans additionally enter
-`jax.named_scope(name)` and `jax.profiler.TraceAnnotation(name)`, so any
-tracing/dispatch performed inside a span shows up under the span's name
-in XLA profiles (neither affects the jit cache — compile-count-guarded in
-tests/test_obs.py). Entering a span is host-side bookkeeping only; it
-never blocks on device work.
+Spans name host time only. Device time is named by the solver's own
+`jax.named_scope`s inside its jitted programs (`bcd`, `sp1`, `sp2` in
+`core/bcd.py`); a span changes no traced program and no compiled shape
+(compile-count-guarded in tests/test_obs.py). Entering a span is
+host-side bookkeeping only; it never blocks on device work.
 
 Event schema conventions (relied on by `obs.report` and the determinism
 tests):
@@ -40,11 +45,18 @@ import threading
 import time
 from typing import Any, Dict, Iterable, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = [
     "Recorder", "NoopRecorder", "MemoryRecorder", "JsonlRecorder",
     "NOOP", "enabled", "get_recorder", "set_recorder", "recording",
     "span", "point", "strip_timing", "TIMING_KEY", "read_jsonl",
 ]
+
+# a span's host event in a profiler trace: TRACE_PREFIX + the span's name
+TRACE_PREFIX = "repro."
+# is a profiler session collecting? (jaxlib's TraceMe check, tens of ns)
+_collecting = TraceAnnotation.is_enabled
 
 
 def TIMING_KEY(key: str) -> bool:
@@ -275,11 +287,11 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One enabled span: times the region, threads parent/child ids, and
-    names the region for XLA profiles via jax.named_scope/TraceAnnotation."""
+    """One recorded span: times the region and threads parent/child ids;
+    inside a profiler session it also writes the region's host event."""
 
     __slots__ = ("rec", "name", "attrs", "id", "parent", "t0", "ts",
-                 "_scopes")
+                 "_trace")
 
     def __init__(self, rec: Recorder, name: str, attrs: Dict[str, Any]):
         self.rec = rec
@@ -287,24 +299,22 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
-        import jax
-
         st = _stack()
         self.parent = st[-1] if st else -1
         self.id = _IDS.take()
         st.append(self.id)
-        self._scopes = (jax.named_scope(self.name),
-                        jax.profiler.TraceAnnotation(self.name))
-        for s in self._scopes:
-            s.__enter__()
+        self._trace = TraceAnnotation(TRACE_PREFIX + self.name) \
+            if _collecting() else None
+        if self._trace is not None:
+            self._trace.__enter__()
         self.ts = time.time()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         dur = time.monotonic() - self.t0
-        for s in reversed(self._scopes):
-            s.__exit__(*exc)
+        if self._trace is not None:
+            self._trace.__exit__(*exc)
         st = _stack()
         if st and st[-1] == self.id:
             st.pop()
@@ -317,13 +327,17 @@ class _Span:
 
 
 def span(name: str, **attrs):
-    """Context manager timing a named region. Near-free when no recorder
-    is enabled (returns one cached null object). Keyword attrs land on the
-    emitted event — keep them deterministic (see module docstring)."""
+    """Context manager timing a named region: an event to the enabled
+    recorder, and a `repro.<name>` host event to a collecting profiler
+    session. Near-free when neither is on (returns one cached null
+    object). Keyword attrs land on the recorder's event only — keep them
+    deterministic (see module docstring)."""
     rec = _RECORDER
-    if not rec.enabled:
-        return _NULL_SPAN
-    return _Span(rec, name, attrs)
+    if rec.enabled:
+        return _Span(rec, name, attrs)
+    if _collecting():
+        return TraceAnnotation(TRACE_PREFIX + name)
+    return _NULL_SPAN
 
 
 def point(name: str, **fields) -> None:

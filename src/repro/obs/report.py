@@ -8,7 +8,7 @@ and prints:
     rebuilt through the same fixed-bucket `Histogram` the live metrics
     use, so the report and the Prometheus/JSONL exports agree;
   * per-stage tables from "stage" points (the region pipeline's
-    queue_wait/plan/dispatch/device/gather samples);
+    queue_wait/plan/dispatch/gather samples);
   * per-request solver-effort counters from "request" points: BCD
     iterations, SP1/SP2 dual evals, final residual, end-to-end latency;
   * a deadline-hit line when any request carried a deadline (the

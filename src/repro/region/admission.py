@@ -61,9 +61,10 @@ class StageClocks:
       queue_wait : per request, batch close - submit
       plan       : per batch, host-side pad/stack/warm-init assembly
       dispatch   : per batch, host time to trace/enqueue the solve
-      device     : per batch, dispatch -> compute observed ready (an upper
-                   bound measured at the batch's first blocking poll)
       gather     : per batch, device->host materialization of responses
+
+    Device time is not a stage: the solver's named scopes (`bcd`, `sp1`,
+    `sp2`) carry it into a profiler trace, where it is measured.
 
     Stages record individual durations via `record(stage, dur)` — the raw
     samples feed real latency distributions (`samples`, `histogram`,
@@ -77,7 +78,7 @@ class StageClocks:
     no distribution data. `as_dict()` keeps its historical aggregate key
     set."""
 
-    STAGES = ("queue_wait", "plan", "dispatch", "device", "gather")
+    STAGES = ("queue_wait", "plan", "dispatch", "gather")
 
     def __init__(self):
         self._samples: Dict[str, List[float]] = {s: [] for s in self.STAGES}

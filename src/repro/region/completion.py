@@ -12,10 +12,9 @@ demand (forcing dispatch first if the request is still queued), so callers
 can hold responses from several in-flight batches and consume them in any
 order — materializing batch k+2 never waits on batch k.
 
-Stage clocks: the in-flight window (dispatch -> compute observed ready,
-an upper bound measured at the first blocking poll) is recorded as a
-per-batch "device" sample; the host-side gather/slice/cache-write time as
-a "gather" sample (see `StageClocks`).
+Stage clocks: the host-side gather/slice/cache-write time after the
+batch's arrays are ready is recorded as a "gather" sample (see
+`StageClocks`).
 
 Telemetry: with a `repro.obs` recorder enabled, materializing a batch
 emits one "request" point per real lane carrying the cell id, warm/bucket
@@ -108,10 +107,8 @@ def materialize(batch: InFlightBatch, cache: WarmStartCache,
     if batch.materialized:
         return [p._response for p in batch.pending]
     plan, res = batch.plan, batch.result
-    t0 = time.monotonic()
     jax.block_until_ready(res.allocation.bandwidth)
     t1 = time.monotonic()
-    clocks.record("device", max(0.0, t1 - batch.t_dispatched))
     # one host transfer per field for the whole batch, then pure-numpy
     # slicing: enqueueing jnp slice ops here would append them to the TAIL
     # of the device stream — behind the next in-flight batch's solve — and

@@ -10,9 +10,8 @@ inside an `InFlightBatch`; the completion layer materializes them later
 batch k+1 on the host while batch k is still computing — double-buffered
 batches with `RegionPipeline.max_in_flight` bounding the queue depth.
 
-Host time spent tracing/enqueueing the solve is charged to
-`StageClocks.dispatch_s`; the in-flight window is observed by the
-completion layer (`device_s`).
+Host time spent tracing/enqueueing the solve is charged to the
+`StageClocks` "dispatch" stage.
 """
 from __future__ import annotations
 
@@ -38,7 +37,6 @@ class InFlightBatch:
     synchronous facade reproduces."""
     plan: BatchPlan
     result: FleetResult
-    t_dispatched: float
     seq: int
     pending: List[Any] = dataclasses.field(default_factory=list)
     materialized: bool = False
@@ -69,9 +67,7 @@ class Dispatcher:
                             acc=self.acc, init=plan.init_batch,
                             mesh=self.mesh), self.spec)
         fleet = res.fleet if hasattr(res, "fleet") else res
-        t1 = time.monotonic()
-        self.clocks.record("dispatch", t1 - t0)
-        batch = InFlightBatch(plan=plan, result=fleet, t_dispatched=t1,
-                              seq=self._seq)
+        self.clocks.record("dispatch", time.monotonic() - t0)
+        batch = InFlightBatch(plan=plan, result=fleet, seq=self._seq)
         self._seq += 1
         return batch

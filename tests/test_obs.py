@@ -453,3 +453,146 @@ def test_jsonl_recorder_drops_when_queue_full(tmp_path):
     assert got == [-1, 0, 1, 2, 3]
     rec.emit({"i": 101})                 # emit-after-close counts as drop
     assert rec.dropped_events == 3
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, solve's stages, the solver's named scopes
+# ---------------------------------------------------------------------------
+
+def _trace_names(logdir) -> set:
+    """Every event name of the newest `*.xplane.pb` under `logdir`."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    path = max(glob.glob(os.path.join(str(logdir), "**", "*.xplane.pb"),
+                         recursive=True), key=os.path.getmtime)
+    return {e.name for p in ProfileData.from_file(path).planes
+            for ln in p.lines for e in ln.events}
+
+
+def test_span_writes_a_trace_event_without_a_recorder(tmp_path):
+    assert not obs.enabled()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert obs.span("x") is not obs.span("y")
+        with obs.span("x"):
+            with obs.span("x.inner", ignored=1):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    # the session closed: back to the one cached null object
+    assert obs.span("x") is obs.span("y")
+    names = _trace_names(tmp_path)
+    assert {"repro.x", "repro.x.inner"} <= names
+
+
+def test_recorded_span_also_writes_a_trace_event(tmp_path):
+    rec = obs.MemoryRecorder()
+    obs.set_recorder(rec)
+    with obs.span("before"):       # no session yet: the recorder only
+        pass
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.span("during", tag="a"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    obs.set_recorder(None)
+    assert [e["name"] for e in rec.events] == ["before", "during"]
+    names = _trace_names(tmp_path)
+    assert "repro.during" in names and "repro.before" not in names
+
+
+def test_spans_enter_no_named_scope():
+    """A span names host time only: inside it JAX's name stack, which a
+    program traced there would carry, is what it was outside."""
+    from jax._src import source_info_util
+
+    outside = str(source_info_util.current_name_stack())
+    obs.set_recorder(obs.MemoryRecorder())
+    with obs.span("hostwork"):
+        assert str(source_info_util.current_name_stack()) == outside
+
+
+def _problems():
+    from repro.region import region_mesh
+
+    key = jax.random.PRNGKey(5)
+    cell = make_system(key, n_devices=6)
+    fleet = stack_systems([make_system(jax.random.fold_in(key, i),
+                                       n_devices=6) for i in range(2)])
+    return {
+        "bcd": Problem(system=cell, weights=W),
+        "bcd_fleet": Problem(system=fleet, weights=W),
+        "bcd_region": Problem(system=fleet, weights=W,
+                              mesh=region_mesh(1)),
+        "fixed_fleet": Problem(system=fleet, weights=W, deadline=40.0),
+    }
+
+
+@pytest.mark.parametrize("topology", ["bcd", "bcd_fleet", "bcd_region",
+                                      "fixed_fleet"])
+def test_solve_stages_nest_under_solve(topology):
+    problem = _problems()[topology]
+    spec = SolverSpec(max_iters=3, tol=1e-4)
+    runs = []
+    for _ in range(2):
+        rec = obs.MemoryRecorder()
+        with obs.recording(rec):
+            solve(problem, spec)
+        runs.append([obs.strip_timing(e) for e in rec.events
+                     if e["type"] == "span"])
+    assert runs[0] == runs[1]          # deterministic ids
+    assert [(e["name"], e["span"], e["parent"]) for e in runs[0]] == [
+        ("solve.prepare", 1, 0), ("solve.launch", 2, 0),
+        ("solve.result", 3, 0), ("solve", 0, -1)]
+    assert runs[0][-1]["topology"] == topology
+
+
+def test_solver_scopes_name_the_device_program():
+    """The compiled fleet solve's ops carry `bcd`, `sp1` and `sp2` in
+    their name stacks (the `tf_op` a device trace shows)."""
+    import re
+
+    from repro.api.problem import weights_leaf
+    from repro.core.accuracy import default_accuracy
+    from repro.core.bcd import _fleet_cell_fn
+    from repro.kernels.ops import kernel_mode
+
+    fleet = stack_systems([make_system(jax.random.PRNGKey(i), n_devices=6)
+                           for i in range(2)])
+    spec = SolverSpec(max_iters=2, tol=1e-4)
+    fn = _fleet_cell_fn(default_accuracy(), spec.max_iters, spec.tol,
+                        spec.sp1_method, spec.sp2_method, spec.sp2_iters,
+                        kernel_mode(), with_init=False)
+    warr = weights_leaf([W, W], jax.numpy.asarray(fleet.gain).dtype, cells=2)
+    hlo = jax.jit(jax.vmap(fn)).lower(fleet, warr).compile().as_text()
+    stacks = [set(n.split("/")) for n in re.findall(r'op_name="([^"]*)"',
+                                                    hlo)]
+    assert any({"bcd", "sp1"} <= s for s in stacks)
+    assert any({"bcd", "sp2"} <= s for s in stacks)
+    assert not any({"sp1", "sp2"} <= s for s in stacks)
+
+
+def test_traced_fleet_solves_add_no_compiled_shapes(compile_counter,
+                                                    tmp_path):
+    fleet = stack_systems([make_system(jax.random.PRNGKey(9 + i),
+                                       n_devices=7) for i in range(2)])
+    problem = Problem(system=fleet, weights=W)
+    spec = SolverSpec(max_iters=3, tol=1e-4)
+    for _ in range(2):                 # compile with both sinks off
+        jax.block_until_ready(solve(problem, spec).objective)
+    before = compile_counter.count
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs.recording(obs.MemoryRecorder()):
+            jax.block_until_ready(solve(problem, spec).objective)
+        jax.block_until_ready(solve(problem, spec).objective)
+    finally:
+        jax.profiler.stop_trace()
+    assert compile_counter.count == before, (
+        f"tracing triggered {compile_counter.count - before} recompiles")
+    assert {"repro.solve", "repro.solve.prepare", "repro.solve.launch",
+            "repro.solve.result"} <= _trace_names(tmp_path)

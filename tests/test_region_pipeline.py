@@ -273,10 +273,10 @@ def test_stage_clocks_cover_all_four_layers():
     assert len(out) == 4
     clocks = pipe.clocks.as_dict()
     assert set(clocks) == {"queue_wait_s", "plan_s", "dispatch_s",
-                           "device_s", "gather_s"}
+                           "gather_s"}
     # logical admission clock: waits are 10-0, 10-1, 10-2, 10-3
     assert clocks["queue_wait_s"] == pytest.approx(34.0)
-    for key in ("plan_s", "dispatch_s", "device_s", "gather_s"):
+    for key in ("plan_s", "dispatch_s", "gather_s"):
         assert clocks[key] > 0.0, key
 
 
