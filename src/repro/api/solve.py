@@ -8,11 +8,11 @@ the jitted impls. `solve` collapses that 4x2 entry-point matrix to one
 code path that routes on `Problem` topology:
 
     single cell        -> BCD (`BCDResult`)
-    (C, N) stack       -> fleet vmap (`FleetResult`)
+    (C, N) stack       -> fleet program, a jitted vmap (`FleetResult`)
     + mesh             -> region shard_map (`RegionResult`)
     + rounds config    -> round-dynamics scan (`RoundsResult`)
     + deadline         -> deadline-constrained BCD (`BCDResult`; on a
-                          (C, N) stack a fleet vmap with per-cell
+                          (C, N) stack a fleet program with per-cell
                           deadlines -> `FleetResult`; + mesh a sharded
                           region solve -> `RegionResult`)
     + assoc config     -> BCD-over-association outer loop on a stacked
@@ -39,8 +39,10 @@ import numpy as np
 from repro import obs
 from repro.core.accuracy import default_accuracy
 from repro.core.bcd import (_FIXED_COLS, _LEDGER_COLS, _allocate_fixed_impl,
-                            _allocate_impl, _fleet_cell_fn, _fleet_result,
-                            _init_carry_state, _materialize_history, BCDResult,
+                            _allocate_impl, _fleet_assemble,
+                            _fleet_fixed_solve_impl, _fleet_result,
+                            _fleet_solve_impl, _init_carry_state,
+                            _materialize_history, BCDResult,
                             SolveCounters, initial_allocation)
 from repro.core.types import Allocation, SystemParams
 from repro.kernels.ops import kernel_mode
@@ -300,22 +302,20 @@ def _solve_fixed_fleet(p: Problem, spec: SolverSpec, sysp, init,
     operand — heterogeneous deadlines never recompile. Returns a
     `FleetResult` with the fixed-variant ledger columns (col 0 "energy" is
     the per-cell objective, matching the single-cell path)."""
-    from repro.core.bcd import _FIXED_COLS, _fleet_fixed_cell_fn
-
     acc = p.acc if p.acc is not None else default_accuracy()
-    dtype = jnp.asarray(sysp.gain).dtype
-    C = int(jnp.asarray(sysp.gain).shape[0])
+    gain = jnp.asarray(sysp.gain)
+    dtype, C = gain.dtype, int(gain.shape[0])
     warr = weights_leaf(p.weights, dtype, cells=C)
     T_round = _per_cell_T_round(p, sysp, C, dtype)
     alloc0 = init if init is not None else jax.vmap(
         lambda sysc: initial_allocation(
             sysc, bandwidth_frac=p.bandwidth_frac))(sysp)
-    fn = _fleet_fixed_cell_fn(acc, spec.max_iters, spec.tol,
-                              spec.sp2_method, spec.sp2_iters)
     stages.to("launch")
-    out = jax.vmap(fn)(sysp, warr, T_round, alloc0)
+    out = _fleet_fixed_solve_impl(
+        sysp, warr, T_round, alloc0, np.asarray(spec.tol, dtype), acc,
+        spec.max_iters, spec.sp2_method, spec.sp2_iters)
     stages.to("result")
-    return _fleet_result(out, spec.max_iters, dtype, cols=_FIXED_COLS)
+    return _fleet_assemble(out, cols=_FIXED_COLS)
 
 
 def _per_cell_T_round(p: Problem, sysp, C: int, dtype):
@@ -375,17 +375,16 @@ def _solve_fixed_region(p: Problem, spec: SolverSpec, sysp, init,
 def _solve_fleet(p: Problem, spec: SolverSpec, sysp, init,
                  stages: _Stages):
     acc = p.acc if p.acc is not None else default_accuracy()
-    dtype = jnp.asarray(sysp.gain).dtype
-    C = int(jnp.asarray(sysp.gain).shape[0])
+    gain = jnp.asarray(sysp.gain)
+    dtype, C = gain.dtype, int(gain.shape[0])
     warr = weights_leaf(p.weights, dtype, cells=C)
-    fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
-                        spec.sp2_method, spec.sp2_iters, kernel_mode(),
-                        with_init=init is not None)
+    tol = np.asarray(spec.tol, dtype)
     stages.to("launch")
-    out = jax.vmap(fn)(sysp, warr) if init is None \
-        else jax.vmap(fn)(sysp, warr, init)
+    out = _fleet_solve_impl(sysp, warr, init, tol, acc, spec.max_iters,
+                            spec.sp1_method, spec.sp2_method, spec.sp2_iters,
+                            kernel_mode(), init is not None)
     stages.to("result")
-    return _fleet_result(out, spec.max_iters, dtype)
+    return _fleet_assemble(out)
 
 
 def _solve_region(p: Problem, spec: SolverSpec, sysp, init,

@@ -32,6 +32,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro import obs
+
 from . import energy as en
 from .accuracy import AccuracyModel
 from .energy import rate as _rate
@@ -479,11 +481,11 @@ def stack_systems(systems: Sequence[SystemParams], xp=jnp) -> SystemParams:
 
 def _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
                    sp2_iters, kernel, with_init: bool):
-    """Per-cell solver closure shared by the fleet vmap and the region
-    shard_map (`api.solve._solve_fleet` / `_solve_region`). The weights
-    array is a *vmapped operand* — each cell carries its own traced (3,)
-    row of a (C, 3) stack, so per-cell/per-request weights share one
-    compiled program."""
+    """Per-cell solver closure shared by the fleet program and the region
+    shard_map (`_fleet_solve_impl` / `region.mesh._region_solve_impl`).
+    The weights array is a *vmapped operand* — each cell carries its own
+    traced (3,) row of a (C, 3) stack, so per-cell/per-request weights
+    share one compiled program."""
     def warm(sysc, warr_c, alloc0):
         state0 = _init_carry_state(sysc, alloc0)
         return _allocate_impl(sysc, warr_c, acc, state0, max_iters, tol,
@@ -496,8 +498,8 @@ def _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
 
 
 def _fleet_fixed_cell_fn(acc, max_iters, tol, sp2_method, sp2_iters):
-    """Per-cell deadline-constrained solver closure for the fleet vmap
-    (`api.solve._solve_fixed_fleet`): the fixed-T sibling of
+    """Per-cell deadline-constrained solver closure for the fleet program
+    (`_fleet_fixed_solve_impl`): the fixed-T sibling of
     `_fleet_cell_fn`. The per-round deadline rides as a vmapped per-cell
     scalar operand, so heterogeneous deadlines (or heterogeneous
     `global_rounds`) share one compiled program."""
@@ -508,19 +510,23 @@ def _fleet_fixed_cell_fn(acc, max_iters, tol, sp2_method, sp2_iters):
     return fn
 
 
-def _fleet_result(out, max_iters: int, dtype,
-                  cols: Sequence[str] = _LEDGER_COLS) -> FleetResult:
-    """Assemble a FleetResult from the stacked raw `_allocate_impl` (or
-    `_allocate_fixed_impl`, with cols=_FIXED_COLS) outputs — all leaves
-    carry a leading cell axis. Ledger column 0 is the per-iteration
-    objective for both column sets ("objective" free / "energy" fixed)."""
-    B, p, f, s, s_hat, T, iters, conv, ledger, counters = out
+def _fleet_objective(iters, ledger, max_iters: int, dtype) -> Array:
+    """Per-cell objective of stacked solves: ledger column 0 at row
+    `iters - 1` (the objective for both column sets, "objective" free /
+    "energy" fixed), NaN where a cell ran no iteration. Pure array ops, so
+    it runs inside the fleet programs and eagerly on mesh outputs alike."""
     if max_iters > 0:
         idx = jnp.clip(iters.astype(jnp.int32) - 1, 0, max_iters - 1)
         last = jnp.take_along_axis(ledger[..., 0], idx[:, None], axis=1)[:, 0]
-        objective = jnp.where(iters > 0, last, jnp.nan)
-    else:
-        objective = jnp.full(iters.shape, jnp.nan, dtype)
+        return jnp.where(iters > 0, last, jnp.nan)
+    return jnp.full(iters.shape, jnp.nan, dtype)
+
+
+def _fleet_assemble(out, cols: Sequence[str] = _LEDGER_COLS) -> FleetResult:
+    """Wrap the stacked raw solve outputs plus the per-cell objective
+    (`_fleet_objective`, appended last) in a FleetResult — dataclasses
+    only, no device op."""
+    B, p, f, s, s_hat, T, iters, conv, ledger, counters, objective = out
     allocation = Allocation(bandwidth=B, power=p, freq=f, resolution=s,
                             s_relaxed=s_hat if cols is _LEDGER_COLS else None,
                             T=T)
@@ -528,6 +534,59 @@ def _fleet_result(out, max_iters: int, dtype,
                        iters=iters, converged=conv, history=ledger,
                        columns=tuple(cols),
                        counters=SolveCounters(data=counters))
+
+
+def _fleet_result(out, max_iters: int, dtype,
+                  cols: Sequence[str] = _LEDGER_COLS) -> FleetResult:
+    """Assemble a FleetResult from the stacked raw `_allocate_impl` (or
+    `_allocate_fixed_impl`, with cols=_FIXED_COLS) outputs — all leaves
+    carry a leading cell axis. The objective is selected in eager ops: the
+    mesh paths, whose programs return the raw outputs, call this."""
+    return _fleet_assemble(
+        (*out, _fleet_objective(out[6], out[8], max_iters, dtype)), cols)
+
+
+@partial(jax.jit, static_argnames=("acc", "max_iters", "sp1_method",
+                                   "sp2_method", "sp2_iters", "kernel",
+                                   "with_init"))
+def _fleet_solve_impl(sys_batch: SystemParams, warr: Array, init, tol,
+                      acc: AccuracyModel, max_iters: int, sp1_method: str,
+                      sp2_method: str, sp2_iters: int, kernel: str,
+                      with_init: bool):
+    """The one-device fleet solve as one compiled program, cached on the
+    shapes and the static solver options: the cold start (unless
+    `with_init`), the vmapped `_fleet_cell_fn` and the per-cell objective.
+    `warr` is the traced (C, 3) weights stack and `tol` a traced scalar.
+    The cold start is computed inside the program, as in
+    `region.mesh._region_solve_impl`, so both paths start from the same
+    bits (compiled, the B/N split is a multiply by 1/N: an ulp off an eager
+    division). Returns the raw stacked outputs with the objective appended
+    (`_fleet_assemble`). The body runs only when traced, so the
+    `solve_fleet_traces` counter stays flat while the cache holds."""
+    obs.counter("solve_fleet_traces").inc()
+    fn = _fleet_cell_fn(acc, max_iters, tol, sp1_method, sp2_method,
+                        sp2_iters, kernel, with_init)
+    args = (sys_batch, warr, init) if with_init else (sys_batch, warr)
+    out = jax.vmap(fn)(*args)
+    return (*out, _fleet_objective(out[6], out[8], max_iters,
+                                   sys_batch.gain.dtype))
+
+
+@partial(jax.jit, static_argnames=("acc", "max_iters", "sp2_method",
+                                   "sp2_iters"))
+def _fleet_fixed_solve_impl(sys_batch: SystemParams, warr: Array, T_round,
+                            alloc0: Allocation, tol, acc: AccuracyModel,
+                            max_iters: int, sp2_method: str, sp2_iters: int):
+    """Deadline-constrained sibling of `_fleet_solve_impl`: the per-cell
+    per-round deadline `T_round` (C,) is a traced operand. The start point
+    `alloc0` comes from the caller, as in `region.mesh._region_fixed_impl`,
+    so both paths start from the same bits. Counted in
+    `solve_fleet_fixed_traces`."""
+    obs.counter("solve_fleet_fixed_traces").inc()
+    fn = _fleet_fixed_cell_fn(acc, max_iters, tol, sp2_method, sp2_iters)
+    out = jax.vmap(fn)(sys_batch, warr, T_round, alloc0)
+    return (*out, _fleet_objective(out[6], out[8], max_iters,
+                                   sys_batch.gain.dtype))
 
 
 def allocate_fleet(sys_batch: SystemParams, w: Weights,

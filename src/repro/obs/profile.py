@@ -148,14 +148,14 @@ def compile_solve(problem, spec=None):
     ``solve.<topology>.C<cells>.N<devices>``. Single-cell and unsharded
     (C, N) fleet topologies only; mesh/rounds/assoc problems are out of
     scope — profile those with `trace`."""
-    import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from repro.api.problem import weights_leaf
     from repro.api.solve import _apply_dtype, _topology_label
     from repro.api.spec import SolverSpec
     from repro.core.accuracy import default_accuracy
-    from repro.core.bcd import (_allocate_impl, _fleet_cell_fn,
+    from repro.core.bcd import (_allocate_impl, _fleet_solve_impl,
                                 _init_carry_state, initial_allocation)
     from repro.kernels.ops import kernel_mode
 
@@ -179,11 +179,10 @@ def compile_solve(problem, spec=None):
         return f"solve.bcd.N{gain.shape[0]}", compiled
     C, N = int(gain.shape[0]), int(gain.shape[1])
     warr = weights_leaf(problem.weights, gain.dtype, cells=C)
-    fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
-                        spec.sp2_method, spec.sp2_iters, kernel_mode(),
-                        with_init=init is not None)
-    args = (sysp, warr) if init is None else (sysp, warr, init)
-    compiled = jax.jit(jax.vmap(fn)).lower(*args).compile()
+    compiled = _fleet_solve_impl.lower(
+        sysp, warr, init, np.asarray(spec.tol, gain.dtype), acc,
+        spec.max_iters, spec.sp1_method, spec.sp2_method, spec.sp2_iters,
+        kernel_mode(), init is not None).compile()
     return f"solve.fleet.C{C}.N{N}", compiled
 
 

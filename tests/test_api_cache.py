@@ -18,7 +18,7 @@ jax.config.update("jax_enable_x64", True)
 import numpy as np
 
 from repro import (AllocationRequest, Problem, RegionAllocator, SolverSpec,
-                   Weights, make_system, solve)
+                   Weights, make_fleet, make_system, obs, solve)
 
 
 def _mk_cells(sizes, seed=0):
@@ -123,3 +123,40 @@ def test_kernel_mode_is_part_of_the_jit_key(compile_counter, monkeypatch):
     interp = solve(problem, spec)
     assert compile_counter.count > before
     np.testing.assert_allclose(interp.objective, ref.objective, rtol=1e-10)
+
+
+def _fleet_traces() -> float:
+    return obs.counter("solve_fleet_traces").value
+
+
+def test_fleet_solve_program_is_cached(compile_counter):
+    """A fleet re-plan runs one cached jitted program: once a shape is
+    warm, new weights and drifted gains neither compile nor re-trace it
+    (`solve_fleet_traces` stays flat), and a new cell or device count
+    traces it exactly once more."""
+    spec = SolverSpec(max_iters=3, tol=1e-4)
+    fleet = make_fleet(jax.random.PRNGKey(11), n_cells=3, n_devices=23)
+    fleet = fleet.replace(gain=np.asarray(fleet.gain))
+    problem = lambda sysp, k: Problem(
+        system=sysp, weights=[Weights(0.2 + 0.1 * c + 0.01 * k,
+                                      0.8 - 0.1 * c, 1.0 + c + k)
+                              for c in range(3)])
+    for k in range(2):              # warm: the program and the host path
+        jax.block_until_ready(solve(problem(fleet, k), spec).objective)
+    before, traces = compile_counter.count, _fleet_traces()
+    for k in range(3):
+        fleet = _drift(fleet, 1.0 + 0.01 * (k + 1))
+        res = solve(problem(fleet, 2 + k), spec)
+        jax.block_until_ready(res.objective)
+        assert bool(np.all(np.isfinite(np.asarray(res.objective))))
+    assert compile_counter.count == before
+    assert _fleet_traces() == traces
+
+    # a new cell count, then a new device count: one trace each
+    for C, N in ((5, 23), (3, 29)):
+        other = make_fleet(jax.random.PRNGKey(12), n_cells=C, n_devices=N)
+        traces = _fleet_traces()
+        for _ in range(2):
+            solve(Problem(system=other, weights=Weights(0.5, 0.5, 1.0)),
+                  spec)
+        assert _fleet_traces() == traces + 1, (C, N)

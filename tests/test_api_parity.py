@@ -6,6 +6,7 @@ Also covers the SolverSpec construction-time validation (tol vs the
 64-ulp rel-step floor) and the `allocate_fixed_deadline` parity satellite
 (max_iters=0 returns NaN, spec options are honored).
 """
+import dataclasses
 import warnings
 
 import jax
@@ -18,10 +19,16 @@ import pytest
 
 from repro import (Problem, SolverSpec, Weights, make_fleet, make_system,
                    rel_step_floor, solve)
+from repro.api.problem import weights_leaf
 from repro.api.solve import _reset_deprecation_registry
+from repro.core.accuracy import default_accuracy
+from repro.core.bcd import (_FIXED_COLS, _fleet_cell_fn, _fleet_fixed_cell_fn,
+                            _fleet_result, initial_allocation, stack_systems)
+from repro.kernels.ops import kernel_mode
 from repro.core import allocate, allocate_fixed_deadline, allocate_fleet
 from repro.dynamics import RoundsConfig, run_rounds_fleet
 from repro.region import allocate_region, region_mesh
+from repro.region.batch import pad_system
 
 W = Weights(0.5, 0.5, 1.0)
 
@@ -127,6 +134,110 @@ def test_fixed_deadline_fleet_matches_per_cell_single_solves():
         system=jax.tree_util.tree_map(lambda x: x[1], fleet),
         weights=w, deadline=120.0), spec)
     assert bool(flat.objective[1] == one.objective)
+
+
+def _eager_fleet(problem: Problem, spec: SolverSpec):
+    """The fleet solve as it ran before the cached fleet programs: a fresh
+    per-cell closure vmapped eagerly on every call, the cold start and the
+    objective selection in eager ops (`_fleet_result`)."""
+    sysp, init = problem.system, problem.init
+    acc = default_accuracy()
+    dtype = jnp.asarray(sysp.gain).dtype
+    C = int(jnp.asarray(sysp.gain).shape[0])
+    warr = weights_leaf(problem.weights, dtype, cells=C)
+    if problem.deadline is None:
+        fn = _fleet_cell_fn(acc, spec.max_iters, spec.tol, spec.sp1_method,
+                            spec.sp2_method, spec.sp2_iters, kernel_mode(),
+                            with_init=init is not None)
+        out = jax.vmap(fn)(sysp, warr) if init is None \
+            else jax.vmap(fn)(sysp, warr, init)
+        return _fleet_result(out, spec.max_iters, dtype)
+    T_round = jnp.broadcast_to(jnp.asarray(problem.deadline, dtype), (C,)) \
+        / jnp.asarray(sysp.global_rounds, dtype)
+    alloc0 = init if init is not None else jax.vmap(
+        lambda sysc: initial_allocation(
+            sysc, bandwidth_frac=problem.bandwidth_frac))(sysp)
+    fn = _fleet_fixed_cell_fn(acc, spec.max_iters, spec.tol,
+                              spec.sp2_method, spec.sp2_iters)
+    out = jax.vmap(fn)(sysp, warr, T_round, alloc0)
+    return _fleet_result(out, spec.max_iters, dtype, cols=_FIXED_COLS)
+
+
+def _fleet_problem(case: str) -> Problem:
+    ws = [Weights(0.9, 0.1, 1.0), Weights(0.5, 0.5, 10.0),
+          Weights(0.2, 0.8, 3.0), Weights(0.6, 0.4, 0.5)]
+    if case == "padded":
+        # ragged pools padded to one bucket: masked lanes in every cell
+        cells = [pad_system(make_system(jax.random.PRNGKey(30 + i),
+                                        n_devices=n), 16)
+                 for i, n in enumerate((16, 11, 7, 14))]
+        return Problem(system=stack_systems(cells), weights=ws)
+    fleet = make_fleet(jax.random.PRNGKey(31), n_cells=4, n_devices=12)
+    if case in ("cold", "fixed"):
+        init = None
+    else:   # warm: start every cell from a drifted fleet's answer
+        drifted = fleet.replace(gain=fleet.gain * 1.05)
+        init = solve(Problem(system=drifted, weights=ws),
+                     SolverSpec(max_iters=4)).allocation
+    deadline = (None if case in ("cold", "warm")
+                else jnp.asarray([90.0, 120.0, 150.0, 200.0]))
+    return Problem(system=fleet, weights=ws, init=init, deadline=deadline,
+                   bandwidth_frac=0.5 if case == "fixed" else 1.0)
+
+
+def _assert_fleets_equal(a, b, exact: bool = True):
+    """Allocation, objective, iterations, convergence, ledger and counters
+    bit for bit; with `exact` False, the values to 1e-12 and the counts
+    that follow from the iterations (BCD iterations, SP1 evaluations)
+    exactly."""
+    pairs = list(zip(jax.tree_util.tree_leaves(a.allocation),
+                     jax.tree_util.tree_leaves(b.allocation)))
+    pairs.append((a.objective, b.objective))
+    if exact:
+        pairs += [(a.history, b.history), (a.counters.data, b.counters.data)]
+    else:
+        ca, cb = a.counters, b.counters
+        for x, y in ((ca.bcd_iters, cb.bcd_iters),
+                     (ca.sp1_evals, cb.sp1_evals)):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+    for x, y in pairs:
+        np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                   rtol=0 if exact else 1e-12, atol=0,
+                                   equal_nan=True)
+    assert a.columns == b.columns
+    assert np.array_equal(np.asarray(a.iters), np.asarray(b.iters))
+    assert np.array_equal(np.asarray(a.converged), np.asarray(b.converged))
+
+
+@pytest.mark.parametrize("case", ["cold", "warm", "padded", "fixed",
+                                  "fixed_warm"])
+def test_fleet_program_matches_eager_vmap(case):
+    """The cached fleet programs (`_fleet_solve_impl`,
+    `_fleet_fixed_solve_impl`) give the eager per-call vmap's answers bit
+    for bit — warm `init`, padded lanes and the deadline variant — and a
+    free solve gives the region program's on a one-device mesh bit for
+    bit.
+
+    The free cold start is the one exception against the eager path: in
+    the compiled program its B/N split is a multiply by 1/N, an ulp off
+    the eager division at N = 12 (the region program starts the same way).
+    That ulp moves the answer by about 1e-14 and can move SP2's evaluation
+    count of a single iteration and the last relative step, so there the
+    values are held to 1e-12 and the counts that follow from the
+    iterations exactly."""
+    problem = _fleet_problem(case)
+    spec = SolverSpec(max_iters=6, tol=1e-8)
+    new = solve(problem, spec)
+    iters, history = np.asarray(new.iters), np.asarray(new.history)
+    assert iters.min() > 0 and iters.max() > 1
+    np.testing.assert_array_equal(   # the objective is the last ledger row's
+        np.asarray(new.objective), history[np.arange(4), iters - 1, 0])
+    _assert_fleets_equal(_eager_fleet(problem, spec), new,
+                         exact=case != "cold")
+    if problem.deadline is None:
+        region = solve(dataclasses.replace(problem, mesh=region_mesh(1)),
+                       spec)
+        _assert_fleets_equal(region.fleet, new)
 
 
 # ---------------------------------------------------------------------------

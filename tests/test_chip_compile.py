@@ -21,7 +21,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from repro import Weights, make_fleet
 from repro.api.problem import weights_leaf
 from repro.core.accuracy import default_accuracy
-from repro.core.bcd import _fleet_cell_fn
+from repro.core.bcd import _fleet_solve_impl
 from repro.core.sp1 import _SWEEP_POINTS
 from repro.kernels import ops
 from repro.kernels.sp1_sweep import N_CONSTS
@@ -89,12 +89,13 @@ def _fleet(cells, n):
 def test_fleet_solve_compiles_for_v5e(one_chip):
     """The whole C64 x N2048 fleet solve `solve()` runs, with the kernel
     compiled by Mosaic inside it, fits one chip."""
-    fn = _fleet_cell_fn(default_accuracy(), SPEC_ARGS["max_iters"],
-                        SPEC_ARGS["tol"], "sweep", "direct", 30, "mosaic",
-                        with_init=False)
     with jax.enable_x64(False):
-        args = _shapes(_fleet(64, 2048), lambda nd: one_chip)
-        compiled = jax.jit(jax.vmap(fn)).lower(*args).compile()
+        sysb, warrb = _shapes(_fleet(64, 2048), lambda nd: one_chip)
+        tol = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
+        compiled = _fleet_solve_impl.lower(
+            sysb, warrb, None, tol, default_accuracy(),
+            SPEC_ARGS["max_iters"], "sweep", "direct", 30, "mosaic",
+            False).compile()
     assert "tpu_custom_call" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16e9

@@ -558,17 +558,18 @@ def test_solver_scopes_name_the_device_program():
 
     from repro.api.problem import weights_leaf
     from repro.core.accuracy import default_accuracy
-    from repro.core.bcd import _fleet_cell_fn
+    from repro.core.bcd import _fleet_solve_impl
     from repro.kernels.ops import kernel_mode
 
     fleet = stack_systems([make_system(jax.random.PRNGKey(i), n_devices=6)
                            for i in range(2)])
     spec = SolverSpec(max_iters=2, tol=1e-4)
-    fn = _fleet_cell_fn(default_accuracy(), spec.max_iters, spec.tol,
-                        spec.sp1_method, spec.sp2_method, spec.sp2_iters,
-                        kernel_mode(), with_init=False)
-    warr = weights_leaf([W, W], jax.numpy.asarray(fleet.gain).dtype, cells=2)
-    hlo = jax.jit(jax.vmap(fn)).lower(fleet, warr).compile().as_text()
+    dtype = jax.numpy.asarray(fleet.gain).dtype
+    warr = weights_leaf([W, W], dtype, cells=2)
+    hlo = _fleet_solve_impl.lower(
+        fleet, warr, None, np.asarray(spec.tol, dtype), default_accuracy(),
+        spec.max_iters, spec.sp1_method, spec.sp2_method, spec.sp2_iters,
+        kernel_mode(), False).compile().as_text()
     stacks = [set(n.split("/")) for n in re.findall(r'op_name="([^"]*)"',
                                                     hlo)]
     assert any({"bcd", "sp1"} <= s for s in stacks)
